@@ -35,6 +35,9 @@ def main() -> int:
 
     import importlib
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     failures = 0
     t_all = time.time()
     for name, mod_name in BENCHES:

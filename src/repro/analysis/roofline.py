@@ -1,10 +1,12 @@
 """Roofline terms from a compiled dry-run artifact.
 
-Three terms per (arch x shape x mesh), TPU v5e constants:
+Three terms per (arch x shape x mesh), from the peak rates of the device
+kind the report is for (`PEAKS`, keyed by `jax.Device.device_kind`; the
+dry-run meshes are TPU v5e, "TPU v5 lite"):
 
-  compute    = HLO_FLOPs_per_device / peak_FLOPs          (197 TF/s bf16)
-  memory     = HLO_bytes_per_device / HBM_bw              (819 GB/s)
-  collective = collective_bytes_per_device / link_bw      (~50 GB/s/link ICI)
+  compute    = HLO_FLOPs_per_device / peak_FLOPs          (v5e: 197 TF/s bf16)
+  memory     = HLO_bytes_per_device / HBM_bw              (v5e: 819 GB/s)
+  collective = collective_bytes_per_device / link_bw      (v5e: ~50 GB/s/link ICI)
 
 ``compiled.cost_analysis()`` yields per-device FLOPs and bytes (the module
 is the post-SPMD per-device program). Collective bytes are NOT in
@@ -20,11 +22,34 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-# TPU v5e, per chip
-PEAK_FLOPS = 197e12  # bf16
-HBM_BW = 819e9  # bytes/s
-ICI_BW = 50e9  # bytes/s per link (intra-pod)
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peak rates."""
+
+    flops: float  # bf16 FLOP/s
+    hbm_bw: float  # bytes/s
+    ici_bw: float  # bytes/s per chip-to-chip link
+    source: str
+
+
+# keyed by jax.Device.device_kind; a kind that is not here is an error
+PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        flops=197e12, hbm_bw=819e9,
+        ici_bw=50e9,  # 1,600 Gbit/s of interconnect over 4 links
+        source='Google Cloud documentation, "TPU v5e"',
+    ),
+}
+DRYRUN_DEVICE_KIND = "TPU v5 lite"  # the chip the production meshes describe
 DCN_BW = 12.5e9  # bytes/s inter-pod (assumed 100 Gb/s NIC-class)
+
+
+def device_peaks(kind: str) -> DevicePeaks:
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise KeyError(f"no peak rates for device kind {kind!r}; add its published "
+                       f"peaks to repro.analysis.roofline.PEAKS") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -191,15 +216,19 @@ class RooflineReport:
     peak_memory_bytes: float  # per-device (temp + args)
     peak_state_bytes: float  # per-device (args + outputs)
     collectives: Dict[str, int]
+    device_kind: str = DRYRUN_DEVICE_KIND
+
+    def __post_init__(self):
+        self.peaks = device_peaks(self.device_kind)
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / self.peaks.flops
 
     @property
     def t_memory_hlo(self) -> float:
         """Unfused upper bound (raw XLA-CPU bytes accessed)."""
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / self.peaks.hbm_bw
 
     @property
     def t_memory(self) -> float:
@@ -211,11 +240,11 @@ class RooflineReport:
         return (
             max(self.adj_bytes_per_device - self.score_bytes_per_device, 0.0)
             + state_rw
-        ) / HBM_BW
+        ) / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        intra = (self.collective_bytes - self.inter_pod_bytes) / ICI_BW
+        intra = (self.collective_bytes - self.inter_pod_bytes) / self.peaks.ici_bw
         inter = self.inter_pod_bytes / DCN_BW
         return intra + inter
 
@@ -240,13 +269,14 @@ class RooflineReport:
         t = max(self.t_compute, self.t_memory, self.t_collective)
         if t <= 0:
             return 0.0
-        return self.model_flops / (t * PEAK_FLOPS * self.n_devices)
+        return self.model_flops / (t * self.peaks.flops * self.n_devices)
 
     def row(self) -> dict:
         return {
             "arch": self.arch,
             "shape": self.shape,
             "mesh": self.mesh,
+            "device_kind": self.device_kind,
             "t_compute_s": self.t_compute,
             "t_memory_s": self.t_memory,
             "t_memory_hlo_s": self.t_memory_hlo,

@@ -12,9 +12,10 @@ this engine keeps the same semantics with fixed-shape state:
 Per hop (== one iteration of Algorithm 5's while loop):
   1. probe cache for all frontier rows                  (lines 6-12)
   2. multi_read the misses from storage, insert to cache (lines 17-27)
-  3. follow continuation chains (bounded depth)
+  3. follow continuation chains (bounded depth), in stages that narrow
+     to the rows still live (`chain_stage_widths`)
   4. mark neighbors in `visited`; next frontier = newly visited nodes
-     (`nonzero(size=F)` keeps shapes static; overflow beyond F is recorded
+     (the first F by node id keep shapes static; overflow beyond F is recorded
      in `truncated` -- with F sized to the h-hop ball this never triggers)
 
 Step 4 -- the visited-bitmap update, the per-round hot loop -- sits behind
@@ -99,7 +100,9 @@ class EngineConfig:
     visited_layout: str = "dense"
     # when the engine runs INSIDE shard_map and multi_read contains
     # collectives (all_to_all), every participant must run the same number of
-    # chain iterations: the loop condition is then psum'd over these axes.
+    # chain iterations: the loop condition is then psum'd over these axes
+    # (the single-host engine names its processor vmap axis here, so the
+    # processors share one loop).
     sync_axes: Optional[Tuple[str, ...]] = None
 
 
@@ -187,6 +190,74 @@ def _read_rows(
     return rows, deg, cont, cache_state, n_probe_miss, n_reads, n_touch
 
 
+CHAIN_SHRINK = 8  # each continuation-chain stage is this many times narrower
+CHAIN_MIN_WIDTH = 4  # rows per query of the narrowest stage
+
+
+def chain_stage_widths(F: int, chain_depth: int) -> Tuple[int, ...]:
+    """Rows per query that each stage of the continuation-chain loop reads.
+
+    A hop's first read covers all F frontier slots of every query, but a
+    row continues only where its node's degree exceeds the row width, so
+    after a few iterations only the hubs' chains are still live -- on a
+    power-law graph a handful per query, for up to ceil(max_degree / W)
+    iterations. Each stage runs while some query still has more live rows
+    than the next stage holds, then the live rows are compacted into it.
+    At most `chain_depth` stages: a stage needs an iteration to run in."""
+    widths = [F]
+    while len(widths) < chain_depth:
+        w = -(-widths[-1] // CHAIN_SHRINK)
+        if w < CHAIN_MIN_WIDTH or w >= widths[-1]:
+            break
+        widths.append(w)
+    return tuple(widths)
+
+
+def _compact_rows(ids: jax.Array, width: int) -> jax.Array:
+    """(B, w) -> (B, width): each query's live (>= 0) ids first, in their
+    original order, so the cache and the read combining see the same
+    sequence of requests as the wider batch (callers ensure every query
+    has at most `width` live ids)."""
+    order = jnp.argsort(ids < 0, axis=1, stable=True)[:, :width]
+    return jnp.take_along_axis(ids, order, axis=1)
+
+
+class _Marks(NamedTuple):
+    """A hop's visited mask and the rows read but not yet marked in it."""
+
+    mask: jax.Array  # visited | marks so far, in the layout's representation
+    rows: jax.Array  # (B, F, W) buffered rows, -1 padded
+    deg: jax.Array  # (B, F)
+    fill: jax.Array  # () rows per query in the buffer
+
+
+class _Chain(NamedTuple):
+    """Carry of the continuation-chain loop of one hop."""
+
+    ids: jax.Array  # (B, w) row ids to read next, -1 padded
+    marks: _Marks
+    cache: CacheState
+    reads: jax.Array
+    touched: jax.Array
+    probe_misses: jax.Array
+    it: jax.Array  # chain iterations so far
+    go: jax.Array  # some row (of the sync group) continues
+
+
+def _first_set(mask: jax.Array, F: int) -> Tuple[jax.Array, jax.Array]:
+    """(B, n) bool -> ((B, F) int32 positions of each row's first F set
+    entries in ascending order, -1 past its last; (B,) set counts).
+
+    The same positions as `jnp.nonzero(row, size=F, fill_value=-1)`, found
+    by binary search in the row's running count: `nonzero` builds them with
+    a bincount, a scatter-add of all n entries into F bins."""
+    c = jnp.cumsum(mask, axis=1, dtype=jnp.int32)
+    k = jnp.arange(1, F + 1, dtype=jnp.int32)
+    pos = jax.vmap(lambda row: jnp.searchsorted(row, k, side="left"))(c)
+    total = c[:, -1]
+    return jnp.where(k[None, :] <= total[:, None], pos, -1).astype(jnp.int32), total
+
+
 def expand_hop(
     tier_arrays,
     cache_state: CacheState,
@@ -208,65 +279,95 @@ def expand_hop(
 
     def _global_any(flag: jax.Array) -> jax.Array:
         """Uniform loop decision: when multi_read contains collectives, every
-        shard_map participant must agree on the trip count."""
+        shard_map participant must agree on the trip count (and a vmapped
+        caller gets one unbatched loop instead of a select per iteration)."""
         if cfg.sync_axes is not None:
             return jax.lax.psum(flag.astype(jnp.int32), cfg.sync_axes) > 0
         return flag
 
-    def chain_body(state):
-        ids, new_mask, cache_state, reads_total, touch_total, probe_total, it, _go = state
-        rows, deg, cont, cache_state, n_probe_miss, n_reads, n_touch = _read_rows(
-            tier_arrays, cache_state, ids, cfg.use_cache, multi_read
-        )
-        reads_total = reads_total + n_reads
-        touch_total = touch_total + n_touch
-        probe_total = probe_total + n_probe_miss
-        # mark neighbors in the per-query mask (pluggable backend). The mask
-        # carries visited | this-hop's marks, not a bare delta, so the
+    def flush(marks: _Marks) -> _Marks:
+        # mark the buffered rows' neighbors (pluggable backend). The mask
+        # carries visited | this hop's marks, not a bare delta, so the
         # packed auto backend's popcount density predicate sees the TRUE
         # bitmap occupancy (already-visited bits can't yield new marks).
-        new_mask = expand_fn(rows.reshape(B, F, W), deg.reshape(B, F), new_mask)
+        return _Marks(expand_fn(marks.rows, marks.deg, marks.mask),
+                      jnp.full_like(marks.rows, -1), jnp.zeros_like(marks.deg),
+                      jnp.zeros((), jnp.int32))
+
+    def mark(marks: _Marks, rows: jax.Array, deg: jax.Array) -> _Marks:
+        w = rows.shape[1]
+        if w == F:  # a full-width read is marked at once
+            return marks._replace(mask=expand_fn(rows, deg, marks.mask))
+        # a narrow read joins the buffer, which is marked when full: one
+        # expansion per F rows per query instead of one per chain iteration
+        # (marks are ORed in, so when they land changes no bit). `fill` is
+        # the same on every processor, so the flush is one branch.
+        marks = jax.lax.cond(marks.fill + w > F, flush, lambda m: m, marks)
+        return _Marks(
+            marks.mask,
+            jax.lax.dynamic_update_slice(marks.rows, rows, (0, marks.fill, 0)),
+            jax.lax.dynamic_update_slice(marks.deg, deg, (0, marks.fill)),
+            marks.fill + w,
+        )
+
+    def chain_body(s: _Chain) -> _Chain:
+        w = s.ids.shape[1]
+        rows, deg, cont, cache_state, n_probe_miss, n_reads, n_touch = _read_rows(
+            tier_arrays, s.cache, s.ids.reshape(-1), cfg.use_cache, multi_read
+        )
         # continuation rows (hub nodes whose adjacency spans multiple rows)
         # are drained in the same hop, as in Algorithm 5's per-hop multi_read
-        cont_flat = cont.reshape(-1)
-        go = _global_any(jnp.any(cont_flat >= 0))
-        return cont_flat, new_mask, cache_state, reads_total, touch_total, probe_total, it + 1, go
+        cont = cont.reshape(B, w)
+        return _Chain(
+            ids=cont,
+            marks=mark(s.marks, rows.reshape(B, w, W), deg.reshape(B, w)),
+            cache=cache_state,
+            reads=s.reads + n_reads,
+            touched=s.touched + n_touch,
+            probe_misses=s.probe_misses + n_probe_miss,
+            it=s.it + 1,
+            go=_global_any(jnp.any(cont >= 0)),
+        )
 
-    def chain_cond(state):
-        *_rest, it, go = state
-        return jnp.logical_and(go, it < cfg.chain_depth)
+    def stage_cond(next_width):
+        def cond(s: _Chain):
+            run = jnp.logical_and(s.go, s.it < cfg.chain_depth)
+            if next_width is None:
+                return run
+            live = jnp.max(jnp.sum(s.ids >= 0, axis=1))
+            return jnp.logical_and(run, _global_any(live > next_width))
+        return cond
 
-    frontier_flat = frontier.reshape(-1)
-    init = (
-        frontier_flat,
-        visited,
-        cache_state,
-        jnp.zeros((), jnp.int32),
-        jnp.zeros((), jnp.int32),
-        jnp.zeros((), jnp.int32),
-        jnp.zeros((), jnp.int32),
-        _global_any(jnp.any(frontier_flat >= 0)),
-    )
-    (
-        _ids, new_mask, cache_state, reads_total, touch_total, probe_total, _it, _go
-    ) = jax.lax.while_loop(chain_cond, chain_body, init)
+    z = jnp.zeros((), jnp.int32)
+    marks = _Marks(visited, jnp.full((B, F, W), -1, jnp.int32), jnp.zeros((B, F), jnp.int32), z)
+    s = _Chain(frontier, marks, cache_state, z, z, z, z, _global_any(jnp.any(frontier >= 0)))
+    widths = chain_stage_widths(F, cfg.chain_depth)
+    for i, width in enumerate(widths):
+        if i:
+            s = s._replace(ids=_compact_rows(s.ids, width))
+        nxt = widths[i + 1] if i + 1 < len(widths) else None
+        s = jax.lax.while_loop(stage_cond(nxt), chain_body, s)
+    marks = s.marks
+    if len(widths) > 1:  # only narrow stages buffer
+        marks = jax.lax.cond(marks.fill > 0, flush, lambda m: m, marks)
+    new_mask = marks.mask
+    # this processor's chains cut off by the chain_depth cap (`s.go` may be
+    # the whole sync group's)
+    chain_cut = jnp.any(s.ids >= 0)
 
     # new_mask == visited | hop marks: the chain carry was seeded with
     # visited and every backend only ORs bits in, so it is already the
     # updated visited set -- no union pass needed in the hot loop
     newly = layout.minus(new_mask, visited)
     visited = new_mask
-    # next frontier = up to F newly-visited nodes per query. `nonzero`
+    # next frontier = up to F newly-visited nodes per query. Finding them
     # needs node positions, so the packed layout unpacks its DELTA here --
     # a per-hop transient XLA can fuse, not state carried across hops.
-    newly_dense = layout.to_dense(newly, n)
-    nxt = jax.vmap(lambda m: jnp.nonzero(m, size=F, fill_value=-1)[0].astype(jnp.int32))(newly_dense)
-    n_new = jnp.sum(newly_dense, axis=1)
+    nxt, n_new = _first_set(layout.to_dense(newly, n), F)
     # truncated if the frontier overflowed F, OR the continuation chain was
     # cut off by the chain_depth cap while rows still had continuations
-    truncated = (n_new > F) | _go
-    return HopResult(visited, nxt, cache_state, truncated, reads_total, touch_total,
-                     probe_total)
+    truncated = (n_new > F) | chain_cut
+    return HopResult(visited, nxt, s.cache, truncated, s.reads, s.touched, s.probe_misses)
 
 
 @dataclasses.dataclass
@@ -316,22 +417,23 @@ def run_neighbor_aggregation(
     layout = get_visited_layout(cfg.visited_layout)
     visited, frontier, valid_q = layout.init_search(queries, n, F)
 
-    misses = jnp.zeros((), jnp.int32)
-    reads = jnp.zeros((), jnp.int32)
-    touched = jnp.zeros((), jnp.int32)
-    truncated = jnp.zeros((B,), bool)
-    # hops is static (h small, 1..4) -> unrolled python loop keeps HLO simple
-    for _ in range(h):
+    def hop(_, carry):
+        visited, frontier, cache_state, misses, reads, touched, truncated, touched_map = carry
         if touched_map is not None:
             ids = frontier.reshape(-1)
             ok = (ids >= 0) & (ids < n)
             touched_map = touched_map.at[jnp.where(ok, ids, 0)].max(ok)
         res = expand_hop(tier_arrays, cache_state, visited, frontier, cfg, multi_read, n)
-        visited, frontier, cache_state = res.visited, res.frontier, res.cache
-        misses = misses + res.probe_misses
-        reads = reads + res.reads
-        touched = touched + res.touched
-        truncated = truncated | res.truncated
+        return (res.visited, res.frontier, res.cache, misses + res.probe_misses,
+                reads + res.reads, touched + res.touched, truncated | res.truncated,
+                touched_map)
+
+    z = jnp.zeros((), jnp.int32)
+    # one hop body in the program, looped h times (its chain stages are the
+    # bulk of the program, so unrolling the hops would multiply it by h)
+    visited, _frontier, cache_state, misses, reads, touched, truncated, touched_map = (
+        jax.lax.fori_loop(0, h, hop, (visited, frontier, cache_state, z, z, z,
+                                      jnp.zeros((B,), bool), touched_map)))
 
     sizes = layout.count(visited)
     counts = sizes - valid_q.astype(jnp.int32)  # exclude query node

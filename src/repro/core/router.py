@@ -64,19 +64,19 @@ class Router:
         self.P = n_processors
         self.config = config
         self.scheme = config.scheme
+        # the scheme's O(n) routing table, placed on the device once. Jitted
+        # callers take it as an ARGUMENT (`route_batch(..., tables=)`), so it
+        # never becomes a constant of a compiled program.
         if self.scheme == "landmark":
             assert landmark_index is not None, "landmark routing needs a LandmarkIndex"
             dtp = landmark_index.dist_to_proc.astype(np.float32)
             dtp = np.where(dtp >= float(UNREACHED), 1e6, dtp)
-            self.dist_to_proc = jnp.asarray(dtp)  # (n, P)
-            self.coords = None
+            self.tables = {"dist_to_proc": jax.device_put(dtp)}  # (n, P)
         elif self.scheme == "embed":
             assert embedding is not None, "embed routing needs a GraphEmbedding"
-            self.coords = jnp.asarray(embedding.coords)  # (n, D)
-            self.dist_to_proc = None
+            self.tables = {"coords": jax.device_put(embedding.coords)}  # (n, D)
         else:
-            self.coords = None
-            self.dist_to_proc = None
+            self.tables = {}
         self.dim = int(embedding.coords.shape[1]) if embedding is not None else 1
         self._seed = seed
 
@@ -85,9 +85,10 @@ class Router:
     def init_state(self) -> RouterState:
         # paper: EMA initialized uniformly at random
         key = jax.random.PRNGKey(self._seed)
-        if self.coords is not None:
-            lo = jnp.min(self.coords, 0)
-            hi = jnp.max(self.coords, 0)
+        if "coords" in self.tables:
+            coords = self.tables["coords"]
+            lo = jnp.min(coords, 0)
+            hi = jnp.max(coords, 0)
             ema = jax.random.uniform(key, (self.P, self.dim)) * (hi - lo) + lo
         else:
             ema = jnp.zeros((self.P, self.dim), jnp.float32)
@@ -99,7 +100,8 @@ class Router:
 
     # -- per-query decision (scanned) ----------------------------------------
 
-    def _decide_one(self, state: RouterState, q: jax.Array) -> Tuple[RouterState, jax.Array]:
+    def _decide_one(self, tables: dict, state: RouterState, q: jax.Array
+                    ) -> Tuple[RouterState, jax.Array]:
         cfg = self.config
         load_term = state.load / cfg.load_factor
         if self.scheme == "next_ready":
@@ -121,11 +123,11 @@ class Router:
             p = jnp.where(steal, idle, p0)
             return dataclasses.replace(state, load=state.load.at[p].add(1.0)), p
         if self.scheme == "landmark":
-            d = self.dist_to_proc[q]  # (P,)
+            d = tables["dist_to_proc"][q]  # (P,)
             p = jnp.argmin(d + load_term).astype(jnp.int32)  # Algorithm 2
             return dataclasses.replace(state, load=state.load.at[p].add(1.0)), p
         if self.scheme == "embed":
-            x = self.coords[q]  # (D,)
+            x = tables["coords"][q]  # (D,)
             d1 = jnp.sqrt(jnp.sum((state.ema - x[None, :]) ** 2, -1) + 1e-12)
             p = jnp.argmin(d1 + load_term).astype(jnp.int32)  # Algorithm 4
             a = cfg.alpha
@@ -138,16 +140,22 @@ class Router:
 
     # -- batched routing -------------------------------------------------------
 
-    @functools.partial(jax.jit, static_argnames=("self",))
-    def route_batch(self, state: RouterState, queries: jax.Array) -> Tuple[RouterState, jax.Array]:
+    def route_batch(self, state: RouterState, queries: jax.Array,
+                    tables: Optional[dict] = None) -> Tuple[RouterState, jax.Array]:
         """Assign a batch of queries sequentially (paper's router is a single
         thread dispatching one query at a time). queries: (B,) int32; negative
         entries are padding -- they get assignment -1 and leave the router
         state (load, EMA, rr) untouched, so fixed-shape round batches can be
-        padded freely. Returns (state', assignment (B,) int32)."""
+        padded freely. Returns (state', assignment (B,) int32).
 
+        `tables` defaults to `self.tables`; a jitted caller passes its own
+        traced copy so the O(n) table stays an argument of its program."""
+        return self._route(self.tables if tables is None else tables, state, queries)
+
+    @functools.partial(jax.jit, static_argnames=("self",))
+    def _route(self, tables: dict, state: RouterState, queries: jax.Array):
         def step(st, q):
-            st2, p = self._decide_one(st, jnp.maximum(q, 0))
+            st2, p = self._decide_one(tables, st, jnp.maximum(q, 0))
             ok = q >= 0
             st3 = jax.tree.map(lambda new, old: jnp.where(ok, new, old), st2, st)
             return st3, jnp.where(ok, p, -1)

@@ -11,11 +11,16 @@ hash-partitioned (MurmurHash3) across storage servers, read with a batched
   + local padded-CSR row gather + all_to_all back. This is byte-for-byte the
   RAMCloud multi_read dataflow with ICI playing Infiniband.
 
-Three entry points:
-  - StorageTier: host-side container + single-device reference `multi_read`.
+Entry points:
+  - StorageTier: host-side container (numpy), built once by build_storage.
+  - StorageArrays / device_storage: the same tables on the device, placed
+    once and passed into jitted serving code as ARGUMENTS -- never closed
+    over, so a compiled program's size does not grow with the graph;
+    multi_read_arrays is the single-device read over them.
   - sharded_multi_read: the shard_map body (pure function of local shards)
     usable inside any shard_map'd serving step.
-  - make_serving_storage: splits rows into per-shard arrays for device_put.
+  - make_serving_storage: the device tables as the dict the shard_map
+    serving step takes.
 """
 
 from __future__ import annotations
@@ -89,26 +94,65 @@ def build_storage(adj: PaddedAdjacency, n_shards: int, seed: int = 0) -> Storage
     )
 
 
-def multi_read_ref(
-    tier: StorageTier, ids: jax.Array
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["rows", "deg", "cont", "owner", "loc"],
+                   meta_fields=["n"])
+@dataclasses.dataclass(frozen=True)
+class StorageArrays:
+    """The storage tier's tables as one pytree of arrays (device or abstract).
+
+    `n` (real nodes, the visited-bitmap width) is static metadata, so a
+    jitted function that takes a StorageArrays gets every O(n) table as an
+    argument and `n` as part of its signature."""
+
+    rows: jax.Array  # (S, rows_per_shard, W) int32
+    deg: jax.Array  # (S, rows_per_shard) int32
+    cont: jax.Array  # (S, rows_per_shard) int32
+    owner: jax.Array  # (n_rows,) int32
+    loc: jax.Array  # (n_rows,) int32
+    n: int
+
+    @property
+    def row_width(self) -> int:
+        return int(self.rows.shape[2])
+
+
+def device_storage(tier: StorageTier, device=None) -> StorageArrays:
+    """Place the tier's tables on `device` (default: the first) once."""
+    put = functools.partial(jax.device_put, device=device)
+    return StorageArrays(
+        rows=put(tier.shard_rows), deg=put(tier.shard_deg),
+        cont=put(tier.shard_cont), owner=put(tier.owner), loc=put(tier.loc),
+        n=tier.n,
+    )
+
+
+def multi_read_arrays(
+    store: StorageArrays, ids: jax.Array
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Single-device reference multi_read (tests / simulator path).
+    """Single-device multi_read over the storage tables.
 
     ids: (B,) int32 row ids (-1 = no-op). Returns (rows (B, W), deg (B,), cont (B,)).
     """
-    owner = jnp.asarray(tier.owner)
-    loc = jnp.asarray(tier.loc)
     safe = jnp.maximum(ids, 0)
-    o, l = owner[safe], loc[safe]
-    rows = jnp.asarray(tier.shard_rows)[o, l]
-    deg = jnp.asarray(tier.shard_deg)[o, l]
-    cont = jnp.asarray(tier.shard_cont)[o, l]
+    o, l = store.owner[safe], store.loc[safe]
+    rows = store.rows[o, l]
+    deg = store.deg[o, l]
+    cont = store.cont[o, l]
     invalid = ids < 0
     return (
         jnp.where(invalid[:, None], -1, rows),
         jnp.where(invalid, 0, deg),
         jnp.where(invalid, -1, cont),
     )
+
+
+def multi_read_ref(
+    tier: StorageTier, ids: jax.Array
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Reference multi_read straight from the host tier (tests / simulator
+    path; under jit its tables become constants of the program)."""
+    return multi_read_arrays(device_storage(tier), ids)
 
 
 # ---------------------------------------------------------------------------
@@ -254,12 +298,8 @@ def stripe_rows(x: np.ndarray, n_shards: int) -> np.ndarray:
 
 
 def make_serving_storage(tier: StorageTier):
-    """Arrays for the distributed path: per-shard rows to be placed with
-    sharding (S=storage axis), plus replicated placement LUTs."""
-    return {
-        "rows": jnp.asarray(tier.shard_rows),  # (S, rows_per_shard, W)
-        "deg": jnp.asarray(tier.shard_deg),
-        "cont": jnp.asarray(tier.shard_cont),
-        "owner": jnp.asarray(tier.owner),
-        "loc": jnp.asarray(tier.loc),
-    }
+    """Arrays for the distributed path: per-shard rows (S, rows_per_shard,
+    W) to be placed with sharding over the storage axis, plus replicated
+    placement LUTs."""
+    store = device_storage(tier)
+    return {k: getattr(store, k) for k in ("rows", "deg", "cont", "owner", "loc")}

@@ -205,12 +205,20 @@ class PackedVisited:
 
 
 def _interpret_mode(backend: str) -> bool:
-    """"pallas"/"auto" pick interpret mode automatically off-TPU so the same
-    config runs everywhere; "-interpret" forces it (CI's CPU kernel path)."""
+    """Interpret mode only when asked for by name ("-interpret": the CPU
+    tests' kernel path). "pallas"/"auto" compile the kernel for a TPU and
+    refuse any other platform instead of quietly interpreting it."""
     if backend not in EXPAND_BACKENDS:
         raise ValueError(
             f"unknown expand_backend {backend!r}; one of {EXPAND_BACKENDS}")
-    return backend.endswith("-interpret") or not on_tpu()
+    if backend.endswith("-interpret"):
+        return True
+    if backend != "scatter" and not on_tpu():
+        raise RuntimeError(
+            f"expand_backend {backend!r} runs its Pallas kernel natively, which "
+            f"needs a TPU (default backend: {jax.default_backend()!r}); "
+            f"ask for {backend}-interpret to run it in the interpreter")
+    return False
 
 
 def _make_expander(backend: str, n: int, scatter_fn: Callable,

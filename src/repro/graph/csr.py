@@ -129,27 +129,57 @@ def to_padded(g: CSRGraph, max_degree: Optional[int] = None) -> PaddedAdjacency:
     n_chain = np.where(deg <= max_degree, 0, np.ceil((deg - max_degree) / max_degree).astype(np.int64))
     total_rows = g.n + int(n_chain.sum())
 
+    # node u's chain rows are consecutive, allocated in node order after the
+    # n base rows; segment k of u (k >= 1) is row chain_start[u] + k - 1
+    chain_start = g.n + np.cumsum(n_chain) - n_chain
     rows = np.full((total_rows, max_degree), -1, dtype=np.int32)
     degree = np.zeros((total_rows,), dtype=np.int32)
     cont = np.full((total_rows,), -1, dtype=np.int32)
 
-    next_free = g.n
-    for u in range(g.n):
-        nb = g.indices[g.indptr[u] : g.indptr[u + 1]]
-        r = u
-        off = 0
-        while True:
-            take = min(max_degree, len(nb) - off)
-            if take > 0:
-                rows[r, :take] = nb[off : off + take]
-            degree[r] = take
-            off += take
-            if off >= len(nb):
-                break
-            cont[r] = next_free
-            r = next_free
-            next_free += 1
+    # every neighbor entry j of u lands in segment j // W, column j % W
+    owner = np.repeat(np.arange(g.n, dtype=np.int64), deg)
+    j = np.arange(g.e, dtype=np.int64) - g.indptr[owner]
+    seg = j // max_degree
+    row_of = np.where(seg == 0, owner, chain_start[owner] + seg - 1)
+    rows[row_of, j % max_degree] = g.indices
+
+    degree[: g.n] = np.minimum(deg, max_degree)
+    has_chain = n_chain > 0
+    cont[: g.n][has_chain] = chain_start[has_chain]
+    # chain rows: segment k = 1..n_chain[u] of each chained node u
+    c_owner = np.repeat(np.arange(g.n, dtype=np.int64), n_chain)
+    c_seg = np.arange(total_rows - g.n, dtype=np.int64) - (chain_start[c_owner] - g.n) + 1
+    degree[g.n :] = np.minimum(deg[c_owner] - c_seg * max_degree, max_degree)
+    last = c_seg == n_chain[c_owner]
+    cont[g.n :] = np.where(last, -1, np.arange(g.n + 1, total_rows + 1))
     return PaddedAdjacency(n=g.n, rows=rows, degree=degree, cont=cont)
+
+
+def iter_bfs_levels(g: CSRGraph, source: int, max_hops: Optional[int] = None):
+    """Host BFS oracle: yields the nodes at hop distance 0, 1, .. max_hops
+    from `source` (until the ball stops growing when max_hops is None), one
+    sorted int64 array per level. Lazy, so a caller stops the search by
+    not asking for the next level; vectorized per level, so a level of a
+    million nodes costs numpy time, not a python loop."""
+    seen = np.zeros(g.n, dtype=bool)
+    seen[source] = True
+    level = np.array([source], dtype=np.int64)
+    hop = 0
+    while level.size:
+        yield level
+        if max_hops is not None and hop >= max_hops:
+            return
+        starts, lens = g.indptr[level], g.indptr[level + 1] - g.indptr[level]
+        pos = np.repeat(starts - np.cumsum(lens) + lens, lens) + np.arange(lens.sum())
+        level = np.unique(g.indices[pos])
+        level = level[~seen[level]].astype(np.int64)
+        seen[level] = True
+        hop += 1
+
+
+def bfs_levels(g: CSRGraph, source: int, max_hops: Optional[int] = None) -> list:
+    """All of `iter_bfs_levels` as a list."""
+    return list(iter_bfs_levels(g, source, max_hops))
 
 
 def csr_to_edge_index(g: CSRGraph) -> Tuple[np.ndarray, np.ndarray]:

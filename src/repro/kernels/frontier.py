@@ -4,13 +4,12 @@ One hop of Algorithm 5: given the adjacency rows of the current frontier
 and the visited bitmap, mark all neighbors visited.
 
 TPU adaptation: vector units have no scatter, so the bitmap update is
-reformulated as a *compare-reduce* over node blocks (DESIGN.md §6):
+reformulated as a *compare-reduce* over node blocks:
 
-  step (b, f): visited[b*BN : (b+1)*BN] |= any_e(nbrs[f-block] == node_ids(b))
+  step (q, b): visited[q, b*BN : (b+1)*BN] |= any_e(nbrs[q] == node_ids(b))
 
-The (BF*W, BN) comparison is a dense vectorizable op; total work is
-O(F*W*n/BN * BN) = O(F*W*n) compares -- FLOP-rich but scatter-free, the
-classic TPU trade. For sparse frontiers the engine's jnp scatter path
+Total work is O(F*W*n) compares per query -- compute-rich but scatter-free,
+the classic TPU trade. For sparse frontiers the engine's jnp scatter path
 (`kernels.ref.frontier_expand_ref` / the `scatter` expansion backend) wins;
 the kernel pays off for dense frontiers where compares are amortized
 (candidate neighbors >= n / DENSE_RATIO, typical in hotspot serving with
@@ -19,21 +18,19 @@ the engine's `auto` expansion backend. Both paths are semantically
 identical (tests sweep shapes; `tests/test_expand_backends.py` is the
 backend-differential oracle).
 
-Entry points (two kernel programs sharing one compare-reduce core):
+Entry points (two kernel programs sharing one chunk loop):
 
   - `frontier_expand_batched`  -- whole admitted batch: rows (B, F, W),
-    visited (B, n) bool; grid (query, node-block, frontier-block) so ONE
-    kernel launch expands every query of a processor round. This is the
-    variant `core.query_engine.expand_hop` mounts behind the `pallas`
-    backend of the DENSE visited layout.
+    visited (B, n) bool; grid (query, node-block) so ONE kernel launch
+    expands every query of a processor round. This is the variant
+    `core.query_engine.expand_hop` mounts behind the `pallas` backend of
+    the DENSE visited layout.
   - `frontier_expand_packed`   -- the BIT-PACKED variant: visited is
     (B, ceil(n/32)) uint32 words (8x smaller than the bool bitmap), grid
-    (query, word-block, frontier-block). Each step runs the same
-    compare-reduce over the bw*32 node ids a word block covers, then packs
-    the hit mask into uint32 words (sum of distinct `1 << bit` powers ==
-    OR) before ORing into the output block. This is the `pallas` backend
-    of the PACKED visited layout (`core.visited.PackedVisited`) -- the
-    representation that unblocks >100K-node visited state.
+    (query, word-block). Each neighbor ORs `1 << (v % 32)` into the lane of
+    word v // 32, and the chunk's rows are OR-reduced one bit plane at a
+    time. This is the `pallas` backend of the PACKED visited layout
+    (`core.visited.PackedVisited`).
   - `frontier_expand`          -- single query: rows (F, W), visited (n,);
     a thin B=1 view over the batched dense kernel.
 
@@ -42,10 +39,26 @@ too: the packed kernel defines the word order (little-endian bits, node id
 = word * 32 + bit), so the pure-jnp pack/unpack math is co-located with it
 and `core.visited` consumes both.
 
-Grid ordering: the frontier-block axis is a reduction (every frontier block
-ORs into the same visited block), so it is the INNERMOST (fastest-varying)
-grid dimension -- output blocks are revisited only on consecutive grid
-steps, the TPU-legal accumulation pattern (same shape as a matmul's k loop).
+TPU layout rules the kernels follow (Mosaic refuses the rest):
+
+  - the query axis is squeezed out of every block (`None` block dim), and a
+    visited row is viewed as (B, 1, n) so its (1, BN) block covers the full
+    second-minor dim; BN and BW are multiples of 128 lanes;
+  - the compare never flattens (F, W) into one axis: each neighbor column
+    (BF, 1) is compared against the node ids on lanes (1, BN);
+  - reductions are int32 maxima over sublanes; packed words are bitcast to
+    int32 around the kernel (Mosaic has no unsigned reductions);
+  - degree masking happens in XLA before the call (`_mask_rows`), so the
+    kernel reads one input besides the bitmap.
+
+The frontier axis is a loop INSIDE the kernel over BF-row chunks; a chunk
+with no valid neighbor is skipped, so a drained or short frontier costs one
+max per chunk. VMEM per grid step, double-buffered inputs: the frontier
+block F_pad * 128 lanes * 4 B * 2 (2 MiB at F=2048; W <= 128 pads to 128
+lanes), the bitmap blocks 4 * 8 sublanes * BN * 4 B (256 KiB at BN=2048;
+bool travels as int32), and the (BF, BN) int32 accumulator (256 KiB at
+BF=32, BN=2048). That is under the 16 MiB default scoped limit of a v5e,
+so no `vmem_limit_bytes` is set.
 
 Retrace discipline: block sizes are never clamped to the input (`min(bf,
 F)` would make the static grid a function of the frontier size and retrace
@@ -64,10 +77,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BF = 128  # frontier rows per block
-DEFAULT_BN = 512  # visited nodes per block (dense kernel)
+DEFAULT_BF = 32  # frontier rows per chunk
+DEFAULT_BN = 2048  # visited nodes per block (dense kernel)
 WORD_BITS = 32  # packed layout: node id = word * 32 + bit (little-endian)
-DEFAULT_BW = 16  # packed words per visited block (= DEFAULT_BN bits)
+DEFAULT_BW = 256  # packed words per visited block (8192 nodes)
 DENSE_RATIO = 8  # compare-reduce pays off once candidates >= n / DENSE_RATIO
 
 # trace-regression instrumentation: each retrace of a jitted padded kernel
@@ -143,14 +156,6 @@ def dense_frontier_packed(
     return jnp.sum(deg) * ratio >= unvisited
 
 
-def _compare_reduce(rows, deg, bn: int, b):
-    """(BF, W) rows + (BF,) deg -> (BN,) hit mask for node block b."""
-    ok = (rows >= 0) & (jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1) < deg[:, None])
-    nbrs = jnp.where(ok, rows, -1).reshape(-1)  # (BF*W,)
-    node_ids = b * bn + jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)  # (1, BN)
-    return jnp.any(nbrs[:, None] == node_ids, axis=0)  # (BN,)
-
-
 def _pad_axis(x: jax.Array, axis: int, pad: int, value) -> jax.Array:
     if pad == 0:
         return x
@@ -173,17 +178,42 @@ def frontier_expand(
     )[0]
 
 
-def _frontier_batched_kernel(rows_ref, deg_ref, vis_in_ref, vis_out_ref, *, bn: int):
-    b, f = pl.program_id(1), pl.program_id(2)
-    hit = _compare_reduce(rows_ref[0], deg_ref[0], bn, b)
+def _mask_rows(rows: jax.Array, deg: jax.Array) -> jax.Array:
+    """Neighbor ids with every invalid entry (past the row's degree, or
+    already -1) set to -1, so the kernels compare ids only. -1 never equals
+    a node id, so padding cannot mark anything."""
+    width = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 2)
+    return jnp.where((rows >= 0) & (width < deg[..., None]), rows, -1)
 
-    @pl.when(f == 0)
-    def _first():
-        vis_out_ref[...] = vis_in_ref[...] | hit[None, :]
 
-    @pl.when(f != 0)
-    def _rest():
-        vis_out_ref[...] = vis_out_ref[...] | hit[None, :]
+def _for_active_chunks(rows_ref, bf: int, body) -> None:
+    """Run body(rows (bf, W)) over the frontier chunks that hold a neighbor.
+
+    A drained frontier (all -1) costs one max per chunk and no compares."""
+
+    def chunk(c, carry):
+        rows = rows_ref[pl.ds(pl.multiple_of(c * bf, bf), bf), :]
+        pl.when(jnp.max(rows) >= 0)(lambda: body(rows))
+        return carry
+
+    jax.lax.fori_loop(0, rows_ref.shape[0] // bf, chunk, 0)
+
+
+def _frontier_batched_kernel(rows_ref, vis_in_ref, vis_out_ref, *, bf: int, bn: int):
+    # rows_ref (Fp, W) masked ids; vis blocks (1, bn) bool, node b*bn + lane
+    ids = pl.program_id(1) * bn + jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
+    vis_out_ref[...] = vis_in_ref[...]
+
+    def mark(rows):
+        # compare one neighbor column at a time: (bf, 1) vs (1, bn) lanes, so
+        # no reshape crosses the (sublane, lane) tiling
+        acc = jnp.zeros((bf, bn), jnp.int32)
+        for w in range(rows.shape[1]):
+            acc = acc | (rows[:, w:w + 1] == ids).astype(jnp.int32)
+        hit = jnp.max(acc, axis=0, keepdims=True) > 0
+        vis_out_ref[...] = vis_out_ref[...] | hit
+
+    _for_active_chunks(rows_ref, bf, mark)
 
 
 @functools.partial(jax.jit, static_argnames=("bf", "bn", "interpret"))
@@ -191,18 +221,21 @@ def _frontier_batched_padded(rows, deg, vis, *, bf: int, bn: int, interpret: boo
     TRACE_COUNTS["frontier_expand_batched"] += 1
     B, Fp, W = rows.shape
     npad = vis.shape[1]
-    return pl.pallas_call(
-        functools.partial(_frontier_batched_kernel, bn=bn),
-        grid=(B, npad // bn, Fp // bf),
+    out = pl.pallas_call(
+        functools.partial(_frontier_batched_kernel, bf=bf, bn=bn),
+        grid=(B, npad // bn),
         in_specs=[
-            pl.BlockSpec((1, bf, W), lambda q, b, f: (q, f, 0)),
-            pl.BlockSpec((1, bf), lambda q, b, f: (q, f)),
-            pl.BlockSpec((1, bn), lambda q, b, f: (q, b)),
+            # the whole frontier of query q: its block index does not change
+            # along the node axis, so it is fetched once per query
+            pl.BlockSpec((None, Fp, W), lambda q, b: (q, 0, 0)),
+            pl.BlockSpec((None, 1, bn), lambda q, b: (q, 0, b)),
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda q, b, f: (q, b)),
-        out_shape=jax.ShapeDtypeStruct((B, npad), vis.dtype),
+        out_specs=pl.BlockSpec((None, 1, bn), lambda q, b: (q, 0, b)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, npad), vis.dtype),
+        input_output_aliases={1: 0},
         interpret=interpret,
-    )(rows, deg, vis)
+    )(_mask_rows(rows, deg), vis.reshape(B, 1, npad))
+    return out.reshape(B, npad)
 
 
 def frontier_expand_batched(
@@ -215,12 +248,12 @@ def frontier_expand_batched(
 ) -> jax.Array:
     """One BFS hop for a whole query batch in ONE kernel launch.
 
-    grid = (query, node-block, frontier-block); each query's rows are the
-    per-hop gather from the cache/storage read results, so this is the
-    expansion step `expand_hop` mounts behind the `pallas` backend. F and n
-    are padded up to whole (bf, bn) blocks here, outside the jit boundary --
-    NOT clamped into the block size -- so any F in the same bf bucket
-    reuses one compiled trace.
+    grid = (query, node-block), frontier chunks looped inside; each query's
+    rows are the per-hop gather from the cache/storage read results, so
+    this is the expansion step `expand_hop` mounts behind the `pallas`
+    backend. F and n are padded up to whole (bf, bn) blocks here, outside
+    the jit boundary -- NOT clamped into the block size -- so any F in the
+    same bf bucket reuses one compiled trace.
     """
     B, F, W = rows.shape
     n = visited.shape[1]
@@ -236,24 +269,28 @@ def frontier_expand_batched(
 # ---------------------------------------------------------------------------
 
 
-def _frontier_packed_kernel(rows_ref, deg_ref, vis_in_ref, vis_out_ref, *, bw: int):
-    b, f = pl.program_id(1), pl.program_id(2)
-    # same compare-reduce core over the bw*32 node ids this word block
-    # covers, then pack: bits are distinct powers of two, so the sum over
-    # the bit axis IS the bitwise OR of the hit mask
-    hit = _compare_reduce(rows_ref[0], deg_ref[0], bw * WORD_BITS, b)
-    bits = jax.lax.broadcasted_iota(jnp.uint32, (bw, WORD_BITS), 1)
-    words = jnp.sum(
-        hit.reshape(bw, WORD_BITS).astype(jnp.uint32) << bits, axis=1
-    ).astype(jnp.uint32)
+def _frontier_packed_kernel(rows_ref, vis_in_ref, vis_out_ref, *, bf: int, bw: int):
+    # vis blocks (1, bw) int32 words, word b*bw + lane; bit j = node word*32+j
+    word = pl.program_id(1) * bw + jax.lax.broadcasted_iota(jnp.int32, (1, bw), 1)
+    vis_out_ref[...] = vis_in_ref[...]
 
-    @pl.when(f == 0)
-    def _first():
-        vis_out_ref[...] = vis_in_ref[...] | words[None, :]
+    def mark(rows):
+        # each neighbor v sets bit v % 32 of word v // 32: OR its bit into the
+        # lane of its word ((-1 >> 5) == -1 matches no word) ...
+        acc = jnp.zeros((bf, bw), jnp.int32)
+        for w in range(rows.shape[1]):
+            v = rows[:, w:w + 1]
+            bit = jnp.left_shift(jnp.int32(1), v & (WORD_BITS - 1))
+            acc = acc | jnp.where((v >> 5) == word, bit, 0)
+        # ... then OR the bf rows together, one bit plane at a time: an int32
+        # max over sublanes is a reduction the TPU has, a bitwise OR is not
+        words = jnp.zeros((1, bw), jnp.int32)
+        for j in range(WORD_BITS):
+            plane = jnp.max((acc >> j) & 1, axis=0, keepdims=True)
+            words = words | (plane << j)
+        vis_out_ref[...] = vis_out_ref[...] | words
 
-    @pl.when(f != 0)
-    def _rest():
-        vis_out_ref[...] = vis_out_ref[...] | words[None, :]
+    _for_active_chunks(rows_ref, bf, mark)
 
 
 @functools.partial(jax.jit, static_argnames=("bf", "bw", "interpret"))
@@ -261,18 +298,21 @@ def _frontier_packed_padded(rows, deg, vis, *, bf: int, bw: int, interpret: bool
     TRACE_COUNTS["frontier_expand_packed"] += 1
     B, Fp, W = rows.shape
     nwpad = vis.shape[1]
-    return pl.pallas_call(
-        functools.partial(_frontier_packed_kernel, bw=bw),
-        grid=(B, nwpad // bw, Fp // bf),
+    # uint32 words travel as int32 bit patterns (no unsigned vector ops on TPU)
+    words = jax.lax.bitcast_convert_type(vis, jnp.int32).reshape(B, 1, nwpad)
+    out = pl.pallas_call(
+        functools.partial(_frontier_packed_kernel, bf=bf, bw=bw),
+        grid=(B, nwpad // bw),
         in_specs=[
-            pl.BlockSpec((1, bf, W), lambda q, b, f: (q, f, 0)),
-            pl.BlockSpec((1, bf), lambda q, b, f: (q, f)),
-            pl.BlockSpec((1, bw), lambda q, b, f: (q, b)),
+            pl.BlockSpec((None, Fp, W), lambda q, b: (q, 0, 0)),
+            pl.BlockSpec((None, 1, bw), lambda q, b: (q, 0, b)),
         ],
-        out_specs=pl.BlockSpec((1, bw), lambda q, b, f: (q, b)),
-        out_shape=jax.ShapeDtypeStruct((B, nwpad), vis.dtype),
+        out_specs=pl.BlockSpec((None, 1, bw), lambda q, b: (q, 0, b)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, nwpad), jnp.int32),
+        input_output_aliases={1: 0},
         interpret=interpret,
-    )(rows, deg, vis)
+    )(_mask_rows(rows, deg), words)
+    return jax.lax.bitcast_convert_type(out.reshape(B, nwpad), jnp.uint32)
 
 
 def frontier_expand_packed(
@@ -286,10 +326,9 @@ def frontier_expand_packed(
 ) -> jax.Array:
     """One BFS hop over the BIT-PACKED visited layout, one kernel launch.
 
-    grid = (query, word-block, frontier-block); each word block covers
-    bw * 32 node ids and ORs packed hit words into the output -- the
-    frontier axis stays innermost (same TPU-legal revisit pattern as the
-    dense kernel). `n` is needed explicitly because the word array
+    grid = (query, word-block), frontier chunks looped inside; each word
+    block covers bw * 32 node ids and ORs packed hit words into the output.
+    `n` is needed explicitly because the word array
     over-covers the id range: ids in [n, words*32) are masked to pad here
     so padding bits inside the last word stay zero and popcount-based
     result counts stay exact. Same pad-up-never-clamp bucketing as the

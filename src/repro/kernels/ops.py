@@ -25,10 +25,11 @@ from repro.kernels.frontier import (
 
 
 def on_tpu() -> bool:
-    """THE backend policy shared by every Pallas-vs-reference switch (here
-    and the engine's expansion-backend seam): Pallas lowers natively only
-    on TPU; everywhere else the kernels run interpreted or fall back to
-    the jnp reference."""
+    """The platform check shared by every Pallas-vs-reference switch:
+    Pallas lowers natively only on TPU. Elsewhere the wrappers below run
+    their kernel interpreted or fall back to the jnp reference, while the
+    engine's expansion backends (`core.visited`) refuse and must be asked
+    for by their `-interpret` name."""
     return jax.default_backend() == "tpu"
 
 

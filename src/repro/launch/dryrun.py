@@ -1,14 +1,18 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes, print memory_analysis / cost_analysis, and dump the
 roofline terms.
 
-The two lines above MUST stay the first statements in this module: jax locks
-the device count at first init, and the dry-run needs 512 placeholder CPU
-devices to build the 2x16x16 mesh. Nothing else in the repo sets this flag
-(smoke tests and benches see the host's single device).
+The three lines above MUST stay the first statements in this module: jax locks
+the platform and device count at first init, and the dry-run needs 512
+placeholder CPU devices to build the 2x16x16 mesh. Pinning the CPU platform
+keeps this process, and the per-cell children `--all` spawns (they inherit
+the environment), from loading the TPU library on a machine with a chip.
+Nothing else in the repo sets these flags (smoke tests and benches see the
+host's single device).
 
 Usage:
   python -m repro.launch.dryrun --arch qwen3-4b --shape train_4k --mesh single

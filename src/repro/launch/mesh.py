@@ -9,15 +9,11 @@ from __future__ import annotations
 import jax
 
 
-def make_auto_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where the installed JAX
-    supports them; plain mesh otherwise (jax.sharding.AxisType landed after
-    0.4.37, where every axis is Auto implicitly)."""
-    try:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
-    except AttributeError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=axis_types)
+def make_auto_mesh(shape, axes, devices=None):
+    """jax.make_mesh with every axis of type Auto (sharding propagated by
+    the compiler); `devices` defaults to `jax.devices()`."""
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

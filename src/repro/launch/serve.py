@@ -5,9 +5,11 @@ synthetic power-law graph, preprocesses landmark/embedding router state,
 and serves the three h-hop query workloads through the event-driven cluster
 (repro.core.serving), printing paper-style throughput/latency/hit-rate rows.
 
-For the REAL device execution path (set-associative caches + all_to_all
-multi_read inside shard_map) use --device-path, which runs the jit'd
-serve step on however many host devices exist."""
+--device-path serves the same workload through the jitted ServingEngine
+instead (set-associative row caches, hash-partitioned storage read with
+multi_read, every processor vmapped on the default device), one result row
+per scheme. The shard_map path across several devices is
+examples/serve_graph.py."""
 
 from __future__ import annotations
 
@@ -15,6 +17,37 @@ import argparse
 import sys
 
 import numpy as np
+
+
+ROW_WIDTH = 32  # storage row width (continuation rows past it)
+CACHE_WAYS = 4
+
+
+def serve_engine(args, g, li, ge, wl, schemes) -> int:
+    """Serve `wl` through ServingEngine under each scheme ("no_cache" is
+    next_ready routing with the row caches off)."""
+    from repro.core.router import Router, RouterConfig
+    from repro.core.storage import build_storage, device_storage
+    from repro.graph.csr import to_padded
+    from repro.serve.engine import EngineRunConfig, ServingEngine
+
+    store = device_storage(build_storage(to_padded(g, max_degree=ROW_WIDTH),
+                                         n_shards=args.processors))
+    max_degree = int(np.diff(g.indptr).max())
+    for scheme in schemes:
+        router = Router(args.processors,
+                        RouterConfig(scheme="next_ready" if scheme == "no_cache" else scheme),
+                        landmark_index=li, embedding=ge)
+        cfg = EngineRunConfig(
+            n_processors=args.processors, round_size=16 * args.processors,
+            capacity=16, hops=args.hops, max_frontier=2048,
+            cache_sets=max(1, args.cache_entries // CACHE_WAYS), cache_ways=CACHE_WAYS,
+            chain_depth=-(-max_degree // ROW_WIDTH), use_cache=scheme != "no_cache",
+        )
+        res, _ = ServingEngine(store, router, cfg).run(wl)
+        res.scheme = scheme
+        print(res.row())
+    return 0
 
 
 def main() -> int:
@@ -54,15 +87,12 @@ def main() -> int:
         "uniform": lambda: uniform_workload(g, seed=1),
     }[args.workload]()
 
-    if args.device_path:
-        print("[serve] device path: see examples/serve_graph.py (jit'd "
-              "shard_map serving step with set-associative caches)")
-        return 0
-
     schemes = (
         ["no_cache", "next_ready", "hash", "landmark", "embed"]
         if args.scheme == "all" else [args.scheme]
     )
+    if args.device_path:
+        return serve_engine(args, g, li, ge, wl, schemes)
     balls = BallCache(g)
     for scheme in schemes:
         rt = SimRouter(args.processors, SimRouterConfig(scheme=scheme),

@@ -65,6 +65,7 @@ the differential oracle for every later scaling PR.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, NamedTuple, Optional, Tuple
 
@@ -82,9 +83,13 @@ from repro.core.query_engine import (
     EngineConfig, QueryStats, run_neighbor_aggregation,
 )
 from repro.core.router import Router, RouterState
-from repro.core.storage import StorageTier, multi_read_ref, sharded_multi_read
+from repro.core.storage import (
+    StorageArrays, StorageTier, device_storage, multi_read_arrays,
+    sharded_multi_read,
+)
 from repro.core.workloads import Workload
 
+PROC_AXIS = "procs"  # vmap axis of the single-host engine's processors
 
 # ---------------------------------------------------------------------------
 # The per-processor serving step (shared: ServingEngine vmap + shard_map path)
@@ -155,10 +160,14 @@ def make_retrying_multi_read(
         out_deg = jnp.zeros(ids.shape, jnp.int32)
         out_cont = jnp.full(ids.shape, -1, jnp.int32)
         pending = ids
-        for _ in range(retries):
+        # a batch no larger than the budget always fits in one round, so a
+        # narrow batch (a late continuation-chain stage) ships a narrow
+        # all_to_all once instead of `retries` full-capacity ones
+        cap = min(capacity, ids.shape[0])
+        for _ in range(retries if cap < ids.shape[0] else 1):
             r, d, c, served = sharded_multi_read(
                 pending, local_rows, local_deg, local_cont, owner_lut, loc_lut,
-                axis_name=axis_name, n_shards=n_shards, capacity=capacity,
+                axis_name=axis_name, n_shards=n_shards, capacity=cap,
             )
             out_rows = jnp.where(served[:, None], r, out_rows)
             out_deg = jnp.where(served, d, out_deg)
@@ -200,10 +209,14 @@ def admission_dispatch(
     fresh_node: jax.Array,
     fresh_qid: jax.Array,
     *,
+    tables: dict,
     capacity: int,
     dispatch_rounds: int,
 ) -> AdmissionRound:
     """One admission round over `backlog ++ fresh` (backlog offered first).
+
+    `tables` are the router's O(n) tables as traced arguments of the
+    caller's program (`Router.tables` passed in, never closed over).
 
     Scoring: the router's pick costs 0, every other processor 1 + its
     current load (so overflow flows to the idlest -- hard stealing). Padded
@@ -218,7 +231,7 @@ def admission_dispatch(
     P = router.P
     off_node, off_qid = backlog_offer(backlog, fresh_node, fresh_qid)
     valid = off_node >= 0
-    rstate, r_assign = router.route_batch(rstate, off_node)
+    rstate, r_assign = router.route_batch(rstate, off_node, tables=tables)
     onehot = jnp.arange(P)[None, :] == r_assign[:, None]
     load_term = rstate.load[None, :] / float(router.config.load_factor)
     scores = jnp.where(onehot, 0.0, 1.0 + load_term)
@@ -371,10 +384,12 @@ class QueueCarry(NamedTuple):
 class ServingEngine:
     """Single-host end-to-end engine over decoupled storage.
 
-    Storage access defaults to the single-device reference `multi_read`
-    (identical dataflow to the sharded all_to_all path; see
-    repro.core.storage); pass `multi_read` to substitute e.g. a
-    capacity-limited or fault-injecting reader.
+    Storage is read with the single-device `multi_read_arrays` (identical
+    dataflow to the sharded all_to_all path; see repro.core.storage). The
+    storage tables and the router's O(n) tables are placed on the device
+    once and enter the jitted scan as arguments: `scan(store, tables,
+    rstate, caches, tmap, qc, xs)` closes over nothing that grows with the
+    graph.
 
     A round need NOT fit the arrival batch (capacity * P may be smaller
     than round_size): overflow carries over through the backlog ring when
@@ -383,32 +398,36 @@ class ServingEngine:
 
     def __init__(
         self,
-        tier: StorageTier,
+        storage: StorageTier | StorageArrays,
         router: Router,
         cfg: EngineRunConfig,
-        multi_read: Optional[Callable] = None,
     ):
         assert router.P == cfg.n_processors, (router.P, cfg.n_processors)
-        self.tier = tier
+        if isinstance(storage, StorageTier):
+            storage = device_storage(storage)
+        self.store = storage
         self.router = router
         self.cfg = cfg
-        self.n = tier.n
-        self._multi_read = multi_read or (lambda ids: multi_read_ref(tier, ids))
+        self.n = storage.n
         self._ecfg = EngineConfig(
             max_frontier=cfg.max_frontier,
             chain_depth=cfg.chain_depth,
             use_cache=cfg.use_cache,
             expand_backend=cfg.expand_backend,
             visited_layout=cfg.visited_layout,
+            # the vmapped processors step through each continuation chain
+            # together: one loop for all, not a per-processor select over
+            # the whole carry (visited state included) every iteration
+            sync_axes=(PROC_AXIS,),
         )
-        self._run_jit = jax.jit(self._run_scan)
+        self.scan = jax.jit(self._run_scan)
 
     # -- state ---------------------------------------------------------------
 
     def init_caches(self) -> CacheState:
         """Stacked per-processor caches: every leaf gains a leading (P,) axis."""
         one = cache_lib.make_cache(
-            self.cfg.cache_sets, self.cfg.cache_ways, self.tier.row_width
+            self.cfg.cache_sets, self.cfg.cache_ways, self.store.row_width
         )
         P = self.cfg.n_processors
         return jax.tree.map(lambda x: jnp.broadcast_to(x[None], (P,) + x.shape), one)
@@ -427,14 +446,14 @@ class ServingEngine:
 
     # -- jit body ------------------------------------------------------------
 
-    def _proc_round(self, cache, queries, touched_map):
+    def _proc_round(self, store, cache, queries, touched_map):
         counts, cache, stats, touched_map = processor_round(
             cache,
             queries,
             h=self.cfg.hops,
             n=self.n,
             ecfg=self._ecfg,
-            multi_read=self._multi_read,
+            multi_read=functools.partial(multi_read_arrays, store),
             touched_map=touched_map,
         )
         scalars = (
@@ -445,7 +464,7 @@ class ServingEngine:
         )
         return counts, cache, scalars, touched_map
 
-    def _round_body(self, carry, xs):
+    def _round_body(self, store, tables, carry, xs):
         cfg = self.cfg
         P, C, B = cfg.n_processors, cfg.slot_capacity, cfg.round_size
         rstate, caches, tmap, qc = carry
@@ -456,14 +475,16 @@ class ServingEngine:
         #      re-queued with drop-oldest admission control.
         adm = admission_dispatch(
             self.router, rstate, qc.backlog, fresh_node, fresh_qid,
-            capacity=C, dispatch_rounds=cfg.dispatch_rounds,
+            tables=tables, capacity=C, dispatch_rounds=cfg.dispatch_rounds,
         )
         rstate, d = adm.rstate, adm.dispatch
         qbuf = gather_by_dispatch(adm.offered_node, d, P, C, fill_value=-1)
 
         # 3. every processor serves its slice (vmapped shared step; a None
         #    touch bitmap is an empty pytree and passes through vmap freely)
-        counts_b, caches, scal, tmap = jax.vmap(self._proc_round)(caches, qbuf, tmap)
+        counts_b, caches, scal, tmap = jax.vmap(
+            functools.partial(self._proc_round, store), axis_name=PROC_AXIS,
+        )(caches, qbuf, tmap)
         touched_p, reads_p, probe_p, trunc_p = scal
         counts = scatter_back(counts_b, d, adm.offered_node.shape[0])
         # unplaced (and padded) queries must not masquerade as |N_h(q)|-1 == 0
@@ -498,8 +519,9 @@ class ServingEngine:
         }
         return (rstate, caches, tmap, qc), ys
 
-    def _run_scan(self, rstate, caches, tmap, qc, xs):
-        return jax.lax.scan(self._round_body, (rstate, caches, tmap, qc), xs)
+    def _run_scan(self, store, tables, rstate, caches, tmap, qc, xs):
+        body = functools.partial(self._round_body, store, tables)
+        return jax.lax.scan(body, (rstate, caches, tmap, qc), xs)
 
     # -- host entry ----------------------------------------------------------
 
@@ -546,7 +568,8 @@ class ServingEngine:
         )
 
         t0 = time.perf_counter()
-        carry, ys = self._run_jit(*state, self._round_inputs(padded, 0, 0, R))
+        fixed = (self.store, self.router.tables)
+        carry, ys = self.scan(*fixed, *state, self._round_inputs(padded, 0, 0, R))
         ys_chunks = [ys]
         n_rounds = R
         if drain and K > 0:
@@ -559,8 +582,8 @@ class ServingEngine:
                 depth = int(np.asarray(carry[3].backlog.depth()))
                 if depth == 0:
                     break
-                carry, ys = self._run_jit(
-                    *carry, self._round_inputs(empty, R * B, n_rounds, D)
+                carry, ys = self.scan(
+                    *fixed, *carry, self._round_inputs(empty, R * B, n_rounds, D)
                 )
                 ys_chunks.append(ys)
                 n_rounds += D

@@ -49,7 +49,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import cache as cache_lib
 from repro.core.dispatch import BacklogState, gather_by_dispatch, make_backlog
@@ -162,8 +161,8 @@ def make_distributed_serve_step(mesh: Mesh, cfg: GServeConfig):
     ) + (proc_p,) * n_cache_leaves
     out_specs = (proc_p, P(), P()) + (proc_p,) * n_cache_leaves
 
-    mapped = shard_map(
-        local_step, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    mapped = jax.shard_map(
+        local_step, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
     def serve_step(inputs: dict):
@@ -197,16 +196,17 @@ def make_admission_round(router, mesh: Mesh, cfg: GServeConfig,
     `make_distributed_serve_step`'s `queries` input expects. Identical
     semantics to the single-host engine's scan body (shared
     `admission_dispatch`), so the differential oracle covers this path too.
+    The router's O(n) tables enter the jitted round as arguments.
     """
     n_proc = n_processors(mesh)
     assert router.P == n_proc, (router.P, n_proc)
     n_rounds = dispatch_rounds if dispatch_rounds > 0 else n_proc
 
     @jax.jit
-    def admission_round(rstate, backlog: BacklogState, fresh_node, fresh_qid
-                        ) -> Tuple[jax.Array, AdmissionRound]:
+    def round_jit(tables, rstate, backlog: BacklogState, fresh_node, fresh_qid
+                  ) -> Tuple[jax.Array, AdmissionRound]:
         adm = admission_dispatch(
-            router, rstate, backlog, fresh_node, fresh_qid,
+            router, rstate, backlog, fresh_node, fresh_qid, tables=tables,
             capacity=cfg.queries_per_proc, dispatch_rounds=n_rounds,
         )
         qbuf = gather_by_dispatch(
@@ -214,6 +214,9 @@ def make_admission_round(router, mesh: Mesh, cfg: GServeConfig,
             fill_value=-1,
         )
         return qbuf, adm
+
+    def admission_round(rstate, backlog, fresh_node, fresh_qid):
+        return round_jit(router.tables, rstate, backlog, fresh_node, fresh_qid)
 
     return admission_round, lambda: make_backlog(backlog_capacity)
 

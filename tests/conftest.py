@@ -58,19 +58,9 @@ def host_mesh():
     return make_auto_mesh((n, 1), ("data", "model"))
 
 
-def bfs_oracle(g, source: int, max_hops: int = 10**9):
-    """Plain python BFS level oracle."""
-    import collections
+def bfs_oracle(g, source: int, max_hops=None):
+    """BFS level oracle as {node: hop distance} (`repro.graph.csr.bfs_levels`)."""
+    from repro.graph.csr import bfs_levels
 
-    dist = {source: 0}
-    q = collections.deque([source])
-    while q:
-        u = q.popleft()
-        if dist[u] >= max_hops:
-            continue
-        for v in g.neighbors(u):
-            v = int(v)
-            if v not in dist:
-                dist[v] = dist[u] + 1
-                q.append(v)
-    return dist
+    return {int(v): d for d, level in enumerate(bfs_levels(g, source, max_hops))
+            for v in level}

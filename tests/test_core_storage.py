@@ -6,7 +6,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from _hypothesis_compat import given, settings, strategies as st
 
 from repro.core.storage import (
@@ -108,11 +107,11 @@ def test_sharded_multi_read_single_device(tiny_graph):
         return sharded_multi_read(ids, rows[0], deg[0], cont[0], owner, loc,
                                   axis_name="model", n_shards=1, capacity=16)
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P("model"), P("model"), P("model"), P(), P()),
         out_specs=(P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     with mesh:
         rows, deg, cont, served = jax.jit(f)(
@@ -136,8 +135,8 @@ def test_sharded_feature_gather_roundtrip():
         return sharded_feature_gather(ids, local, axis_name="model",
                                       n_shards=1, capacity=16)
 
-    f = shard_map(body, mesh=mesh, in_specs=(P(), P("model")),
-                  out_specs=(P(), P()), check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P(), P("model")),
+                  out_specs=(P(), P()), check_vma=False)
     with mesh:
         out, served = jax.jit(f)(ids, jnp.asarray(striped))
     out = np.asarray(out)
@@ -155,3 +154,29 @@ def test_stripe_rows_layout():
     # row r lives at shard r%3, slot r//3 -> flat index (r%3)*3 + r//3
     for r in range(7):
         np.testing.assert_array_equal(s[(r % 3) * 3 + r // 3], x[r])
+
+
+def test_engine_program_size_does_not_grow_with_the_graph():
+    """The storage tier and the router's O(n) table enter the jitted serving
+    scan as arguments: its program text is the same size for a 20x larger
+    graph. A table closed over as a constant would add >= 8 bytes of text
+    per node."""
+    from repro.core.embedding import EmbedConfig, GraphEmbedding
+    from repro.core.router import Router, RouterConfig
+    from repro.graph.generators import powerlaw_graph
+    from repro.serve.engine import EngineRunConfig, ServingEngine
+
+    sizes = []
+    for n in (2000, 40000):
+        g = powerlaw_graph(n=n, m=4, seed=0)
+        tier = build_storage(to_padded(g, max_degree=8), n_shards=2)
+        emb = GraphEmbedding(coords=np.random.default_rng(0).random((n, 4), np.float32),
+                             landmarks=np.arange(4), lm_coords=np.zeros((4, 4), np.float32),
+                             config=EmbedConfig(dim=4))
+        router = Router(2, RouterConfig(scheme="embed"), embedding=emb)
+        eng = ServingEngine(tier, router, EngineRunConfig(
+            n_processors=2, round_size=8, hops=2, max_frontier=16, cache_sets=16))
+        state = (router.init_state(), eng.init_caches(), eng.init_touched(), eng.init_queue())
+        xs = eng._round_inputs(np.zeros(8, np.int32), 0, 0, 1)
+        sizes.append(len(eng.scan.lower(eng.store, router.tables, *state, xs).as_text()))
+    assert sizes[1] < sizes[0] + 2000, sizes

@@ -7,7 +7,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.graph.generators import powerlaw_graph
 from repro.graph.csr import csr_to_edge_index, to_padded
@@ -221,8 +220,8 @@ def test_grad_compression_error_feedback():
         synced, ef = compressed_psum({"w": gw}, "data")
         return synced["w"], ef.residual["w"]
 
-    f = shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
-                  check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
+                  check_vma=False)
     with mesh:
         synced, resid = jax.jit(f)(g["w"])
     # int8 quantization error bounded by scale/2 per element

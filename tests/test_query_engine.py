@@ -174,3 +174,17 @@ def test_chain_truncation_flagged(engine, tiny_graph):
     _, _, stats, _ = run_neighbor_aggregation(
         None, cache, q, 1, g.n, cfg, make_ref_multi_read(tier))
     assert bool(np.asarray(stats.truncated)[0])
+
+
+@pytest.mark.parametrize("n,F,density", [(300, 16, 0.02), (300, 16, 0.5), (1000, 64, 0.0),
+                                         (64, 64, 1.0), (513, 7, 0.1)])
+def test_first_set_equals_nonzero(n, F, density):
+    """The frontier extraction returns exactly `jnp.nonzero(size=F,
+    fill_value=-1)` per row, and each row's set count."""
+    from repro.core.query_engine import _first_set
+
+    mask = jnp.asarray(np.random.default_rng(n + F).random((5, n)) < density)
+    pos, count = _first_set(mask, F)
+    want = jax.vmap(lambda m: jnp.nonzero(m, size=F, fill_value=-1)[0])(mask)
+    np.testing.assert_array_equal(np.asarray(pos), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(count), np.asarray(mask.sum(axis=1)))
