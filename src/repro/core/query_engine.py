@@ -115,6 +115,9 @@ class HopResult(NamedTuple):
     reads: jax.Array  # () int32 -- unique storage rows fetched
     touched: jax.Array  # () int32 -- rows needed (hits + misses)
     probe_misses: jax.Array  # () int32 -- missed cache probes (incl. batch dups)
+    chain_iters: jax.Array  # (stages,) int32 -- chain-loop iterations per stage
+    chain_rows: jax.Array  # (stages,) int32 -- live row ids read per stage
+    flushes: jax.Array  # () int32 -- buffered-mark flushes
 
 
 def _dedup_first(ids: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -165,23 +168,27 @@ def _read_rows(
         # unique rows only; every probe still counts as a miss (no cache).
         first, src = _dedup_first(jnp.where(valid, ids, -1))
         uniq = valid & first
-        rows, deg, cont = multi_read(jnp.where(uniq, ids, -1))
+        with jax.named_scope("storage_read"):
+            rows, deg, cont = multi_read(jnp.where(uniq, ids, -1))
         rows, deg, cont = rows[src], deg[src], cont[src]
         n_reads = jnp.sum(uniq).astype(jnp.int32)
         return rows, deg, cont, cache_state, n_touch, n_reads, n_touch
-    found, c_rows, c_deg, c_cont, cache_state = cache_lib.cache_lookup(
-        cache_state, ids, valid
-    )
+    with jax.named_scope("cache_lookup"):
+        found, c_rows, c_deg, c_cont, cache_state = cache_lib.cache_lookup(
+            cache_state, ids, valid
+        )
     miss = valid & ~found
     first, src = _dedup_first(jnp.where(miss, ids, -1))
     uniq = miss & first
     fetch_ids = jnp.where(uniq, ids, -1)
-    s_rows, s_deg, s_cont = multi_read(fetch_ids)
+    with jax.named_scope("storage_read"):
+        s_rows, s_deg, s_cont = multi_read(fetch_ids)
     # duplicates of a missed id read the first occurrence's fetched row
     s_rows, s_deg, s_cont = s_rows[src], s_deg[src], s_cont[src]
-    cache_state = cache_lib.cache_insert(
-        cache_state, fetch_ids, s_rows, s_deg, s_cont, valid=uniq
-    )
+    with jax.named_scope("cache_insert"):
+        cache_state = cache_lib.cache_insert(
+            cache_state, fetch_ids, s_rows, s_deg, s_cont, valid=uniq
+        )
     rows = jnp.where(found[:, None], c_rows, s_rows)
     deg = jnp.where(found, c_deg, s_deg)
     cont = jnp.where(found, c_cont, s_cont)
@@ -229,6 +236,7 @@ class _Marks(NamedTuple):
     rows: jax.Array  # (B, F, W) buffered rows, -1 padded
     deg: jax.Array  # (B, F)
     fill: jax.Array  # () rows per query in the buffer
+    flushes: jax.Array  # () flushes so far
 
 
 class _Chain(NamedTuple):
@@ -285,29 +293,32 @@ def expand_hop(
             return jax.lax.psum(flag.astype(jnp.int32), cfg.sync_axes) > 0
         return flag
 
+    def expand(rows: jax.Array, deg: jax.Array, mask: jax.Array) -> jax.Array:
+        with jax.named_scope("mark"):
+            return expand_fn(rows, deg, mask)
+
     def flush(marks: _Marks) -> _Marks:
         # mark the buffered rows' neighbors (pluggable backend). The mask
         # carries visited | this hop's marks, not a bare delta, so the
         # packed auto backend's popcount density predicate sees the TRUE
         # bitmap occupancy (already-visited bits can't yield new marks).
-        return _Marks(expand_fn(marks.rows, marks.deg, marks.mask),
+        return _Marks(expand(marks.rows, marks.deg, marks.mask),
                       jnp.full_like(marks.rows, -1), jnp.zeros_like(marks.deg),
-                      jnp.zeros((), jnp.int32))
+                      jnp.zeros((), jnp.int32), marks.flushes + 1)
 
     def mark(marks: _Marks, rows: jax.Array, deg: jax.Array) -> _Marks:
         w = rows.shape[1]
         if w == F:  # a full-width read is marked at once
-            return marks._replace(mask=expand_fn(rows, deg, marks.mask))
+            return marks._replace(mask=expand(rows, deg, marks.mask))
         # a narrow read joins the buffer, which is marked when full: one
         # expansion per F rows per query instead of one per chain iteration
         # (marks are ORed in, so when they land changes no bit). `fill` is
         # the same on every processor, so the flush is one branch.
         marks = jax.lax.cond(marks.fill + w > F, flush, lambda m: m, marks)
-        return _Marks(
-            marks.mask,
-            jax.lax.dynamic_update_slice(marks.rows, rows, (0, marks.fill, 0)),
-            jax.lax.dynamic_update_slice(marks.deg, deg, (0, marks.fill)),
-            marks.fill + w,
+        return marks._replace(
+            rows=jax.lax.dynamic_update_slice(marks.rows, rows, (0, marks.fill, 0)),
+            deg=jax.lax.dynamic_update_slice(marks.deg, deg, (0, marks.fill)),
+            fill=marks.fill + w,
         )
 
     def chain_body(s: _Chain) -> _Chain:
@@ -339,17 +350,22 @@ def expand_hop(
         return cond
 
     z = jnp.zeros((), jnp.int32)
-    marks = _Marks(visited, jnp.full((B, F, W), -1, jnp.int32), jnp.zeros((B, F), jnp.int32), z)
+    marks = _Marks(visited, jnp.full((B, F, W), -1, jnp.int32), jnp.zeros((B, F), jnp.int32),
+                   z, z)
     s = _Chain(frontier, marks, cache_state, z, z, z, z, _global_any(jnp.any(frontier >= 0)))
     widths = chain_stage_widths(F, cfg.chain_depth)
-    for i, width in enumerate(widths):
-        if i:
-            s = s._replace(ids=_compact_rows(s.ids, width))
-        nxt = widths[i + 1] if i + 1 < len(widths) else None
-        s = jax.lax.while_loop(stage_cond(nxt), chain_body, s)
-    marks = s.marks
-    if len(widths) > 1:  # only narrow stages buffer
-        marks = jax.lax.cond(marks.fill > 0, flush, lambda m: m, marks)
+    ends = []  # (iterations, rows read) so far at the end of each stage
+    with jax.named_scope("chain"):
+        for i, width in enumerate(widths):
+            if i:
+                s = s._replace(ids=_compact_rows(s.ids, width))
+            nxt = widths[i + 1] if i + 1 < len(widths) else None
+            s = jax.lax.while_loop(stage_cond(nxt), chain_body, s)
+            ends.append((s.it, s.touched))
+        marks = s.marks
+        if len(widths) > 1:  # only narrow stages buffer
+            marks = jax.lax.cond(marks.fill > 0, flush, lambda m: m, marks)
+    chain_iters, chain_rows = (jnp.diff(jnp.stack(c), prepend=0) for c in zip(*ends))
     new_mask = marks.mask
     # this processor's chains cut off by the chain_depth cap (`s.go` may be
     # the whole sync group's)
@@ -358,16 +374,18 @@ def expand_hop(
     # new_mask == visited | hop marks: the chain carry was seeded with
     # visited and every backend only ORs bits in, so it is already the
     # updated visited set -- no union pass needed in the hot loop
-    newly = layout.minus(new_mask, visited)
-    visited = new_mask
     # next frontier = up to F newly-visited nodes per query. Finding them
     # needs node positions, so the packed layout unpacks its DELTA here --
     # a per-hop transient XLA can fuse, not state carried across hops.
-    nxt, n_new = _first_set(layout.to_dense(newly, n), F)
+    with jax.named_scope("next_frontier"):
+        newly = layout.minus(new_mask, visited)
+        nxt, n_new = _first_set(layout.to_dense(newly, n), F)
+    visited = new_mask
     # truncated if the frontier overflowed F, OR the continuation chain was
     # cut off by the chain_depth cap while rows still had continuations
     truncated = (n_new > F) | chain_cut
-    return HopResult(visited, nxt, s.cache, truncated, s.reads, s.touched, s.probe_misses)
+    return HopResult(visited, nxt, s.cache, truncated, s.reads, s.touched, s.probe_misses,
+                     chain_iters, chain_rows, marks.flushes)
 
 
 @dataclasses.dataclass
@@ -382,6 +400,13 @@ class QueryStats:
     `truncated_fwd`/`truncated_bwd` are only populated by `run_reachability`
     (per-direction detail of its bi-directional BFS: `truncated` is their
     OR); every other query type leaves them None.
+
+    `chain_iters`/`chain_rows`/`flushes` are the continuation-chain loop's
+    work, populated by `run_neighbor_aggregation`: iterations and live row
+    ids read per hop and per stage of `chain_stage_widths` (`chain_rows`
+    sums to `touched`), and flushes of the buffered narrow-stage marks.
+    Each stage-0 iteration marks its full-width read at once, so the
+    expansions of the visited state are stage-0 iterations plus flushes.
     """
 
     touched: jax.Array  # rows needed across hops (hits+misses)
@@ -391,6 +416,9 @@ class QueryStats:
     reads: jax.Array  # unique storage rows fetched
     truncated_fwd: Optional[jax.Array] = None  # (B,) bool, reachability only
     truncated_bwd: Optional[jax.Array] = None  # (B,) bool, reachability only
+    chain_iters: Optional[jax.Array] = None  # (h, stages) int32
+    chain_rows: Optional[jax.Array] = None  # (h, stages) int32
+    flushes: Optional[jax.Array] = None  # () int32
 
 
 def run_neighbor_aggregation(
@@ -417,8 +445,9 @@ def run_neighbor_aggregation(
     layout = get_visited_layout(cfg.visited_layout)
     visited, frontier, valid_q = layout.init_search(queries, n, F)
 
-    def hop(_, carry):
-        visited, frontier, cache_state, misses, reads, touched, truncated, touched_map = carry
+    def hop(i, carry):
+        (visited, frontier, cache_state, misses, reads, touched, truncated, touched_map,
+         iters, rows, flushes) = carry
         if touched_map is not None:
             ids = frontier.reshape(-1)
             ok = (ids >= 0) & (ids < n)
@@ -426,20 +455,24 @@ def run_neighbor_aggregation(
         res = expand_hop(tier_arrays, cache_state, visited, frontier, cfg, multi_read, n)
         return (res.visited, res.frontier, res.cache, misses + res.probe_misses,
                 reads + res.reads, touched + res.touched, truncated | res.truncated,
-                touched_map)
+                touched_map, iters.at[i].set(res.chain_iters),
+                rows.at[i].set(res.chain_rows), flushes + res.flushes)
 
     z = jnp.zeros((), jnp.int32)
+    per_stage = jnp.zeros((h, len(chain_stage_widths(F, cfg.chain_depth))), jnp.int32)
     # one hop body in the program, looped h times (its chain stages are the
     # bulk of the program, so unrolling the hops would multiply it by h)
-    visited, _frontier, cache_state, misses, reads, touched, truncated, touched_map = (
-        jax.lax.fori_loop(0, h, hop, (visited, frontier, cache_state, z, z, z,
-                                      jnp.zeros((B,), bool), touched_map)))
+    (visited, _frontier, cache_state, misses, reads, touched, truncated, touched_map,
+     iters, rows, flushes) = jax.lax.fori_loop(
+        0, h, hop, (visited, frontier, cache_state, z, z, z, jnp.zeros((B,), bool),
+                    touched_map, per_stage, per_stage, z))
 
     sizes = layout.count(visited)
     counts = sizes - valid_q.astype(jnp.int32)  # exclude query node
     stats = QueryStats(
         touched=touched, misses=misses, result_sizes=sizes,
-        truncated=truncated, reads=reads,
+        truncated=truncated, reads=reads, chain_iters=iters, chain_rows=rows,
+        flushes=flushes,
     )
     return counts, cache_state, stats, touched_map
 

@@ -72,6 +72,7 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import cache as cache_lib
 from repro.core.cache import CacheState
@@ -229,37 +230,39 @@ def admission_dispatch(
     offered: the router always scores them against current load/EMA.)
     """
     P = router.P
-    off_node, off_qid = backlog_offer(backlog, fresh_node, fresh_qid)
-    valid = off_node >= 0
-    rstate, r_assign = router.route_batch(rstate, off_node, tables=tables)
-    onehot = jnp.arange(P)[None, :] == r_assign[:, None]
-    load_term = rstate.load[None, :] / float(router.config.load_factor)
-    scores = jnp.where(onehot, 0.0, 1.0 + load_term)
-    scores = jnp.where(valid[:, None], scores, jnp.inf)
-    d = capacity_dispatch(scores, capacity=capacity, n_rounds=dispatch_rounds)
-    placed = valid & (d.assignment >= 0)
-    routed = jnp.bincount(
-        jnp.where(valid, r_assign, P), length=P + 1
-    )[:P].astype(jnp.float32)
-    rstate = dataclasses.replace(rstate, load=rstate.load - routed)
-    leftover = valid & ~placed
-    backlog, dropped, depth, n_dropped = backlog_admit(
-        off_node, off_qid, leftover, backlog.capacity
-    )
-    return AdmissionRound(
-        rstate=rstate,
-        backlog=backlog,
-        offered_node=off_node,
-        offered_qid=off_qid,
-        r_assign=r_assign,
-        dispatch=d,
-        placed=placed,
-        dropped=dropped,
-        depth=depth,
-        n_dropped=n_dropped,
-        stolen=jnp.sum(placed & (d.assignment != r_assign)).astype(jnp.int32),
-        unplaced=jnp.sum(leftover).astype(jnp.int32),
-    )
+    with jax.named_scope("admission"):
+        off_node, off_qid = backlog_offer(backlog, fresh_node, fresh_qid)
+        valid = off_node >= 0
+        with jax.named_scope("route"):
+            rstate, r_assign = router.route_batch(rstate, off_node, tables=tables)
+        onehot = jnp.arange(P)[None, :] == r_assign[:, None]
+        load_term = rstate.load[None, :] / float(router.config.load_factor)
+        scores = jnp.where(onehot, 0.0, 1.0 + load_term)
+        scores = jnp.where(valid[:, None], scores, jnp.inf)
+        d = capacity_dispatch(scores, capacity=capacity, n_rounds=dispatch_rounds)
+        placed = valid & (d.assignment >= 0)
+        routed = jnp.bincount(
+            jnp.where(valid, r_assign, P), length=P + 1
+        )[:P].astype(jnp.float32)
+        rstate = dataclasses.replace(rstate, load=rstate.load - routed)
+        leftover = valid & ~placed
+        backlog, dropped, depth, n_dropped = backlog_admit(
+            off_node, off_qid, leftover, backlog.capacity
+        )
+        return AdmissionRound(
+            rstate=rstate,
+            backlog=backlog,
+            offered_node=off_node,
+            offered_qid=off_qid,
+            r_assign=r_assign,
+            dispatch=d,
+            placed=placed,
+            dropped=dropped,
+            depth=depth,
+            n_dropped=n_dropped,
+            stolen=jnp.sum(placed & (d.assignment != r_assign)).astype(jnp.int32),
+            unplaced=jnp.sum(leftover).astype(jnp.int32),
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -461,6 +464,9 @@ class ServingEngine:
             stats.reads,
             stats.misses,
             jnp.any(stats.truncated),
+            stats.chain_iters,
+            stats.chain_rows,
+            stats.flushes,
         )
         return counts, cache, scalars, touched_map
 
@@ -478,15 +484,17 @@ class ServingEngine:
             tables=tables, capacity=C, dispatch_rounds=cfg.dispatch_rounds,
         )
         rstate, d = adm.rstate, adm.dispatch
-        qbuf = gather_by_dispatch(adm.offered_node, d, P, C, fill_value=-1)
+        with jax.named_scope("admission"):
+            qbuf = gather_by_dispatch(adm.offered_node, d, P, C, fill_value=-1)
 
         # 3. every processor serves its slice (vmapped shared step; a None
         #    touch bitmap is an empty pytree and passes through vmap freely)
         counts_b, caches, scal, tmap = jax.vmap(
             functools.partial(self._proc_round, store), axis_name=PROC_AXIS,
         )(caches, qbuf, tmap)
-        touched_p, reads_p, probe_p, trunc_p = scal
-        counts = scatter_back(counts_b, d, adm.offered_node.shape[0])
+        touched_p, reads_p, probe_p, trunc_p, iters_p, rows_p, flushes_p = scal
+        with jax.named_scope("admission"):
+            counts = scatter_back(counts_b, d, adm.offered_node.shape[0])
         # unplaced (and padded) queries must not masquerade as |N_h(q)|-1 == 0
         counts = jnp.where(adm.placed, counts, -1)
 
@@ -512,6 +520,9 @@ class ServingEngine:
             "reads": reads_p,
             "probe_misses": probe_p,
             "truncated": trunc_p,
+            "chain_iters": iters_p,  # (P, hops, stages)
+            "chain_rows": rows_p,  # (P, hops, stages)
+            "flushes": flushes_p,  # (P,)
             "stolen": adm.stolen,
             "unplaced": adm.unplaced,
             "backlog_depth": adm.depth,
@@ -567,37 +578,51 @@ class ServingEngine:
             "to the PREVIOUS workload; finish it with drain=True first"
         )
 
+        # host spans, on the profiler's clock when it traces: input copies,
+        # dispatch of the scan, the wait for the device, the fetch of the
+        # per-round outputs, and the per-query reconstruction
         t0 = time.perf_counter()
         fixed = (self.store, self.router.tables)
-        carry, ys = self.scan(*fixed, *state, self._round_inputs(padded, 0, 0, R))
-        ys_chunks = [ys]
-        n_rounds = R
-        if drain and K > 0:
-            # drain in fixed-size chunks (one extra compile, reused across
-            # chunks); every round with a non-empty ring places >= 1 query,
-            # so <= K extra rounds suffice.
-            D = max(1, -(-K // max(1, P * C)))
-            empty = np.full(D * B, -1, np.int32)
-            for _ in range(K + 1):
-                depth = int(np.asarray(carry[3].backlog.depth()))
-                if depth == 0:
-                    break
-                carry, ys = self.scan(
-                    *fixed, *carry, self._round_inputs(empty, R * B, n_rounds, D)
+        with TraceAnnotation("engine.inputs"):
+            xs = self._round_inputs(padded, 0, 0, R)
+        with TraceAnnotation("engine.scan"):
+            carry, ys = self.scan(*fixed, *state, xs)
+            ys_chunks = [ys]
+            n_rounds = R
+            if drain and K > 0:
+                # drain in fixed-size chunks (one extra compile, reused across
+                # chunks); every round with a non-empty ring places >= 1 query,
+                # so <= K extra rounds suffice.
+                D = max(1, -(-K // max(1, P * C)))
+                empty = np.full(D * B, -1, np.int32)
+                for _ in range(K + 1):
+                    depth = int(np.asarray(carry[3].backlog.depth()))
+                    if depth == 0:
+                        break
+                    with TraceAnnotation("engine.inputs"):
+                        xs = self._round_inputs(empty, R * B, n_rounds, D)
+                    carry, ys = self.scan(*fixed, *carry, xs)
+                    ys_chunks.append(ys)
+                    n_rounds += D
+                assert int(np.asarray(carry[3].backlog.depth())) == 0, (
+                    "backlog failed to drain"
                 )
-                ys_chunks.append(ys)
-                n_rounds += D
-            assert int(np.asarray(carry[3].backlog.depth())) == 0, (
-                "backlog failed to drain"
-            )
-        jax.block_until_ready(ys_chunks[-1]["counts"])
+        with TraceAnnotation("engine.wait"):
+            jax.block_until_ready(ys_chunks[-1]["counts"])
         wall = time.perf_counter() - t0
-        ys = {
-            k: np.concatenate([np.asarray(c[k]) for c in ys_chunks], axis=0)
-            for k in ys_chunks[0]
-        }
+        with TraceAnnotation("engine.fetch"):
+            ys = {
+                k: np.concatenate([np.asarray(c[k]) for c in ys_chunks], axis=0)
+                for k in ys_chunks[0]
+            }
+        with TraceAnnotation("engine.outcomes"):
+            return self._outcomes(ys, carry, q0, Q, n_rounds, wall)
 
-        # -- reconstruct per-query outcomes from the per-round offer logs ----
+    def _outcomes(self, ys: dict, carry: tuple, q0: QueueCarry, Q: int, n_rounds: int,
+                  wall: float) -> Tuple[EngineResult, tuple]:
+        """Per-query outcomes from the per-round offer logs, checked against
+        the scan's own counters (`QueueCarry`)."""
+        B = self.cfg.round_size
         counts = np.full(Q, -1, np.int32)
         assign = np.full(Q, -1, np.int32)
         r_assign = np.full(Q, -1, np.int32)
