@@ -14,7 +14,7 @@ vector of keys and fully jit-able; this preserves the paper's LRU recency
 semantics (exactly LRU within each set) while mapping onto TPU vector units.
 
 The cache stores padded adjacency rows: data[set, way, :] = neighbor ids,
-deg[set, way] = valid count, cont[set, way] = continuation row id (see
+deg[set, way] = remaining degree, cont[set, way] = continuation row id (see
 repro.graph.csr.PaddedAdjacency).
 """
 
@@ -87,12 +87,16 @@ def _hash_keys(keys: jax.Array, n_sets: int) -> jax.Array:
 
 
 def cache_lookup(
-    state: CacheState, keys: jax.Array, valid: jax.Array | None = None
+    state: CacheState, keys: jax.Array, valid: jax.Array | None = None,
+    probes: jax.Array | None = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, CacheState]:
     """Batched probe.
 
     keys: (B,) int32 node ids (may contain -1 / invalid entries).
     valid: optional (B,) bool mask; invalid keys never hit and don't count.
+    probes: optional (B,) int32 -- how many probes each key stands for in
+    the hit/miss counters (a pooled key read once for several queries);
+    default one each.
 
     Returns (found (B,) bool, rows (B, W) int32, degs (B,), conts (B,),
     new_state with refreshed ages + stats).
@@ -115,8 +119,10 @@ def cache_lookup(
     new_age = state.age.at[
         jnp.where(found, sets, 0), jnp.where(found, way, 0)
     ].max(jnp.where(found, state.clock + 1, -1), mode="drop")
-    n_hit = jnp.sum(found & valid).astype(jnp.int32)
-    n_miss = jnp.sum(valid).astype(jnp.int32) - n_hit
+    if probes is None:
+        probes = jnp.ones(keys.shape, jnp.int32)
+    n_hit = jnp.sum(jnp.where(found & valid, probes, 0)).astype(jnp.int32)
+    n_miss = jnp.sum(jnp.where(valid, probes, 0)).astype(jnp.int32) - n_hit
     new_state = dataclasses.replace(
         state,
         age=new_age,

@@ -12,8 +12,11 @@ this engine keeps the same semantics with fixed-shape state:
 Per hop (== one iteration of Algorithm 5's while loop):
   1. probe cache for all frontier rows                  (lines 6-12)
   2. multi_read the misses from storage, insert to cache (lines 17-27)
-  3. follow continuation chains (bounded depth), in stages that narrow
-     to the rows still live (`chain_stage_widths`)
+  3. read the continuation rows of the frontier's hubs: a base row's
+     remaining degree and first continuation id name every row of its
+     chain (rows are consecutive, `graph.csr.to_padded`), so each
+     processor pools its queries' chains, deduplicated and ordered by row
+     id, and reads that list F ids per iteration (bounded depth per node)
   4. mark neighbors in `visited`; next frontier = newly visited nodes
      (the first F by node id keep shapes static; overflow beyond F is recorded
      in `truncated` -- with F sized to the h-hop ball this never triggers)
@@ -82,9 +85,10 @@ from repro.core.visited import (  # noqa: F401  (re-exports)
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
     max_frontier: int = 2048  # F
-    chain_depth: int = 64  # max continuation-row chasing per hop (safety cap;
-    #                         the chain loop exits as soon as no row has a
-    #                         continuation, so typical cost is 1-2 iterations)
+    chain_depth: int = 64  # max rows of one node read per hop, base row
+    #                         included (a cap; the chain loop reads the pooled
+    #                         continuation rows F ids per iteration, so its
+    #                         cost follows the rows, not this depth)
     use_cache: bool = True
     # frontier-expansion backend: how step 4 (neighbors -> visited bitmap)
     # executes. One of EXPAND_BACKENDS: "scatter" (XLA scatter, the
@@ -115,9 +119,9 @@ class HopResult(NamedTuple):
     reads: jax.Array  # () int32 -- unique storage rows fetched
     touched: jax.Array  # () int32 -- rows needed (hits + misses)
     probe_misses: jax.Array  # () int32 -- missed cache probes (incl. batch dups)
-    chain_iters: jax.Array  # (stages,) int32 -- chain-loop iterations per stage
-    chain_rows: jax.Array  # (stages,) int32 -- live row ids read per stage
-    flushes: jax.Array  # () int32 -- buffered-mark flushes
+    chain_iters: jax.Array  # (2,) int32 -- iterations: base read, packed loop
+    chain_rows: jax.Array  # (2,) int32 -- (query, row) pairs read per stage
+    chain_unique: jax.Array  # (2,) int32 -- distinct row ids read per stage
 
 
 def _dedup_first(ids: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -146,6 +150,7 @@ def _read_rows(
     ids: jax.Array,
     use_cache: bool,
     multi_read: Callable,
+    probes: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, CacheState, jax.Array, jax.Array, jax.Array]:
     """Cache-first row read with intra-batch read combining.
 
@@ -160,9 +165,14 @@ def _read_rows(
     Returns (rows, deg, cont, cache', n_probe_miss, n_reads, n_touch):
     n_probe_miss counts missed probes (consistent with the cache's own hit/
     miss counters); n_reads counts unique rows actually fetched from storage.
+    `probes` (M,) int32, default one each, is how many probes each id stands
+    for in n_touch and n_probe_miss: a pooled id read once for several
+    queries counts as each of their probes.
     """
     valid = ids >= 0
-    n_touch = jnp.sum(valid).astype(jnp.int32)
+    if probes is None:
+        probes = jnp.ones(ids.shape, jnp.int32)
+    n_touch = jnp.sum(jnp.where(valid, probes, 0)).astype(jnp.int32)
     if not use_cache:
         # read combining is a multi_read property, not a cache one: fetch
         # unique rows only; every probe still counts as a miss (no cache).
@@ -175,7 +185,7 @@ def _read_rows(
         return rows, deg, cont, cache_state, n_touch, n_reads, n_touch
     with jax.named_scope("cache_lookup"):
         found, c_rows, c_deg, c_cont, cache_state = cache_lib.cache_lookup(
-            cache_state, ids, valid
+            cache_state, ids, valid, probes
         )
     miss = valid & ~found
     first, src = _dedup_first(jnp.where(miss, ids, -1))
@@ -192,64 +202,65 @@ def _read_rows(
     rows = jnp.where(found[:, None], c_rows, s_rows)
     deg = jnp.where(found, c_deg, s_deg)
     cont = jnp.where(found, c_cont, s_cont)
-    n_probe_miss = jnp.sum(miss).astype(jnp.int32)
+    n_probe_miss = jnp.sum(jnp.where(miss, probes, 0)).astype(jnp.int32)
     n_reads = jnp.sum(uniq).astype(jnp.int32)
     return rows, deg, cont, cache_state, n_probe_miss, n_reads, n_touch
 
 
-CHAIN_SHRINK = 8  # each continuation-chain stage is this many times narrower
-CHAIN_MIN_WIDTH = 4  # rows per query of the narrowest stage
+class _Pool(NamedTuple):
+    """A processor's continuation rows of one hop as one flat list: the
+    chains its queries' frontiers name, deduplicated, ordered by row id and
+    laid end to end. Chain u holds list positions [end[u] - len, end[u]),
+    and list position p of chain u is row id shift[u] + p."""
+
+    end: jax.Array  # (M,) int32 list position past each chain's last row
+    shift: jax.Array  # (M,) int32 first row id minus first list position
+    mult: jax.Array  # (M,) int32 queries whose frontier holds the chain
+    hold: jax.Array  # (B, M) bool -- query b's frontier holds chain u
+    total: jax.Array  # () int32 rows in the list
+    cut: jax.Array  # () bool -- a chain is longer than the cap
 
 
-def chain_stage_widths(F: int, chain_depth: int) -> Tuple[int, ...]:
-    """Rows per query that each stage of the continuation-chain loop reads.
+def _pool_chains(deg: jax.Array, cont: jax.Array, row_width: int, cap: int) -> _Pool:
+    """Pool the chains that a hop's base rows name.
 
-    A hop's first read covers all F frontier slots of every query, but a
-    row continues only where its node's degree exceeds the row width, so
-    after a few iterations only the hubs' chains are still live -- on a
-    power-law graph a handful per query, for up to ceil(max_degree / W)
-    iterations. Each stage runs while some query still has more live rows
-    than the next stage holds, then the live rows are compacted into it.
-    At most `chain_depth` stages: a stage needs an iteration to run in."""
-    widths = [F]
-    while len(widths) < chain_depth:
-        w = -(-widths[-1] // CHAIN_SHRINK)
-        if w < CHAIN_MIN_WIDTH or w >= widths[-1]:
-            break
-        widths.append(w)
-    return tuple(widths)
-
-
-def _compact_rows(ids: jax.Array, width: int) -> jax.Array:
-    """(B, w) -> (B, width): each query's live (>= 0) ids first, in their
-    original order, so the cache and the read combining see the same
-    sequence of requests as the wider batch (callers ensure every query
-    has at most `width` live ids)."""
-    order = jnp.argsort(ids < 0, axis=1, stable=True)[:, :width]
-    return jnp.take_along_axis(ids, order, axis=1)
-
-
-class _Marks(NamedTuple):
-    """A hop's visited mask and the rows read but not yet marked in it."""
-
-    mask: jax.Array  # visited | marks so far, in the layout's representation
-    rows: jax.Array  # (B, F, W) buffered rows, -1 padded
-    deg: jax.Array  # (B, F)
-    fill: jax.Array  # () rows per query in the buffer
-    flushes: jax.Array  # () flushes so far
+    deg, cont: (B, F) remaining degree and first continuation row of each
+    frontier slot's base row (0 / -1 for padding). A node of degree
+    deg > row_width owns the consecutive rows cont + j for
+    j < ceil(deg / row_width) - 1 (`graph.csr.to_padded`); the first `cap`
+    of them are read. A node in several frontiers is one chain."""
+    B, F = deg.shape
+    M = B * F
+    n_cont = jnp.where(cont >= 0, (deg + row_width - 1) // row_width - 1, 0)
+    length = jnp.minimum(n_cont, cap).reshape(-1)
+    key = jnp.where(length > 0, cont.reshape(-1), jnp.iinfo(jnp.int32).max)
+    order = jnp.argsort(key)
+    s = key[order]
+    live = length[order] > 0
+    first = live & jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]])
+    u = jnp.where(live, jnp.cumsum(first, dtype=jnp.int32) - 1, M)  # M: none
+    head = jnp.where(first, u, M)
+    start = jnp.zeros((M,), jnp.int32).at[head].set(s, mode="drop")
+    chain_len = jnp.zeros((M,), jnp.int32).at[head].set(length[order], mode="drop")
+    end = jnp.cumsum(chain_len, dtype=jnp.int32)
+    slot_chain = jnp.zeros((M,), jnp.int32).at[order].set(u).reshape(B, F)
+    hold = jnp.zeros((B, M), bool).at[jnp.arange(B)[:, None], slot_chain].set(
+        True, mode="drop")
+    return _Pool(end=end, shift=start - (end - chain_len),
+                 mult=jnp.zeros((M,), jnp.int32).at[u].add(1, mode="drop"),
+                 hold=hold, total=end[-1], cut=jnp.any(n_cont > cap))
 
 
 class _Chain(NamedTuple):
-    """Carry of the continuation-chain loop of one hop."""
+    """Carry of the packed chain loop of one hop."""
 
-    ids: jax.Array  # (B, w) row ids to read next, -1 padded
-    marks: _Marks
+    it: jax.Array  # iterations so far: list positions [0, it * F) are read
+    mask: jax.Array  # visited | this hop's marks, in the layout's representation
     cache: CacheState
     reads: jax.Array
     touched: jax.Array
     probe_misses: jax.Array
-    it: jax.Array  # chain iterations so far
-    go: jax.Array  # some row (of the sync group) continues
+    unique: jax.Array  # list rows read
 
 
 def _first_set(mask: jax.Array, F: int) -> Tuple[jax.Array, jax.Array]:
@@ -277,99 +288,89 @@ def expand_hop(
 ) -> HopResult:
     """One BFS hop for a batch of queries sharing one processor cache.
 
+    Stage 0 reads every frontier slot's base row and marks them at once.
+    The packed stage reads the processor's pooled continuation rows
+    (`_pool_chains`) F ids per iteration and marks each query's rows among
+    them at once, so every iteration of either stage is one expansion.
+
     `visited` is in the layout selected by `cfg.visited_layout`; the
     visited-bitmap update delegates to that layout's expansion backend
     (`cfg.expand_backend`). Both seams resolve once, python-static."""
     B, F = frontier.shape
     W = cache_state.row_width
+    M = B * F
     layout = get_visited_layout(cfg.visited_layout)
     expand_fn = layout.expander(cfg.expand_backend, n)
 
     def _global_any(flag: jax.Array) -> jax.Array:
         """Uniform loop decision: when multi_read contains collectives, every
         shard_map participant must agree on the trip count (and a vmapped
-        caller gets one unbatched loop instead of a select per iteration)."""
+        caller gets one unbatched loop or branch instead of a select)."""
         if cfg.sync_axes is not None:
             return jax.lax.psum(flag.astype(jnp.int32), cfg.sync_axes) > 0
         return flag
 
     def expand(rows: jax.Array, deg: jax.Array, mask: jax.Array) -> jax.Array:
+        # mark the rows' neighbors (pluggable backend). The mask carries
+        # visited | this hop's marks, not a bare delta, so the packed auto
+        # backend's popcount density predicate sees the TRUE bitmap
+        # occupancy (already-visited bits can't yield new marks).
         with jax.named_scope("mark"):
             return expand_fn(rows, deg, mask)
 
-    def flush(marks: _Marks) -> _Marks:
-        # mark the buffered rows' neighbors (pluggable backend). The mask
-        # carries visited | this hop's marks, not a bare delta, so the
-        # packed auto backend's popcount density predicate sees the TRUE
-        # bitmap occupancy (already-visited bits can't yield new marks).
-        return _Marks(expand(marks.rows, marks.deg, marks.mask),
-                      jnp.full_like(marks.rows, -1), jnp.zeros_like(marks.deg),
-                      jnp.zeros((), jnp.int32), marks.flushes + 1)
-
-    def mark(marks: _Marks, rows: jax.Array, deg: jax.Array) -> _Marks:
-        w = rows.shape[1]
-        if w == F:  # a full-width read is marked at once
-            return marks._replace(mask=expand(rows, deg, marks.mask))
-        # a narrow read joins the buffer, which is marked when full: one
-        # expansion per F rows per query instead of one per chain iteration
-        # (marks are ORed in, so when they land changes no bit). `fill` is
-        # the same on every processor, so the flush is one branch.
-        marks = jax.lax.cond(marks.fill + w > F, flush, lambda m: m, marks)
-        return marks._replace(
-            rows=jax.lax.dynamic_update_slice(marks.rows, rows, (0, marks.fill, 0)),
-            deg=jax.lax.dynamic_update_slice(marks.deg, deg, (0, marks.fill)),
-            fill=marks.fill + w,
+    def base_read(carry):
+        cache, mask = carry
+        rows, deg, cont, cache, n_probe_miss, n_reads, n_touch = _read_rows(
+            tier_arrays, cache, frontier.reshape(-1), cfg.use_cache, multi_read
         )
+        deg, cont = deg.reshape(B, F), cont.reshape(B, F)
+        mask = expand(rows.reshape(B, F, W), deg, mask)
+        return cache, mask, deg, cont, jnp.stack([n_reads, n_touch, n_probe_miss])
 
-    def chain_body(s: _Chain) -> _Chain:
-        w = s.ids.shape[1]
-        rows, deg, cont, cache_state, n_probe_miss, n_reads, n_touch = _read_rows(
-            tier_arrays, s.cache, s.ids.reshape(-1), cfg.use_cache, multi_read
+    def no_read(carry):
+        cache, mask = carry
+        return (cache, mask, jnp.zeros((B, F), jnp.int32), jnp.full((B, F), -1, jnp.int32),
+                jnp.zeros((3,), jnp.int32))
+
+    def packed_body(s: _Chain) -> _Chain:
+        p = s.it * F + jnp.arange(F, dtype=jnp.int32)
+        ok = p < pool.total
+        c = jnp.minimum(jnp.searchsorted(pool.end, p, side="right"), M - 1)
+        rows, deg, _cont, cache, n_probe_miss, n_reads, n_touch = _read_rows(
+            tier_arrays, s.cache, jnp.where(ok, pool.shift[c] + p, -1), cfg.use_cache,
+            multi_read, probes=jnp.where(ok, pool.mult[c], 0),
         )
-        # continuation rows (hub nodes whose adjacency spans multiple rows)
-        # are drained in the same hop, as in Algorithm 5's per-hop multi_read
-        cont = cont.reshape(B, w)
+        # each query marks its own rows of the read: the chains its frontier
+        # holds (`pool.hold`), the rest masked out
+        member = pool.hold[:, c] & ok[None, :]
         return _Chain(
-            ids=cont,
-            marks=mark(s.marks, rows.reshape(B, w, W), deg.reshape(B, w)),
-            cache=cache_state,
+            it=s.it + 1,
+            mask=expand(jnp.where(member[..., None], rows[None], -1),
+                        jnp.where(member, deg[None], 0), s.mask),
+            cache=cache,
             reads=s.reads + n_reads,
             touched=s.touched + n_touch,
             probe_misses=s.probe_misses + n_probe_miss,
-            it=s.it + 1,
-            go=_global_any(jnp.any(cont >= 0)),
+            unique=s.unique + jnp.sum(ok, dtype=jnp.int32),
         )
 
-    def stage_cond(next_width):
-        def cond(s: _Chain):
-            run = jnp.logical_and(s.go, s.it < cfg.chain_depth)
-            if next_width is None:
-                return run
-            live = jnp.max(jnp.sum(s.ids >= 0, axis=1))
-            return jnp.logical_and(run, _global_any(live > next_width))
-        return cond
-
     z = jnp.zeros((), jnp.int32)
-    marks = _Marks(visited, jnp.full((B, F, W), -1, jnp.int32), jnp.zeros((B, F), jnp.int32),
-                   z, z)
-    s = _Chain(frontier, marks, cache_state, z, z, z, z, _global_any(jnp.any(frontier >= 0)))
-    widths = chain_stage_widths(F, cfg.chain_depth)
-    ends = []  # (iterations, rows read) so far at the end of each stage
     with jax.named_scope("chain"):
-        for i, width in enumerate(widths):
-            if i:
-                s = s._replace(ids=_compact_rows(s.ids, width))
-            nxt = widths[i + 1] if i + 1 < len(widths) else None
-            s = jax.lax.while_loop(stage_cond(nxt), chain_body, s)
-            ends.append((s.it, s.touched))
-        marks = s.marks
-        if len(widths) > 1:  # only narrow stages buffer
-            marks = jax.lax.cond(marks.fill > 0, flush, lambda m: m, marks)
-    chain_iters, chain_rows = (jnp.diff(jnp.stack(c), prepend=0) for c in zip(*ends))
-    new_mask = marks.mask
-    # this processor's chains cut off by the chain_depth cap (`s.go` may be
-    # the whole sync group's)
-    chain_cut = jnp.any(s.ids >= 0)
+        # stage 0: every frontier slot's base row, marked at once
+        go = _global_any(jnp.any(frontier >= 0))
+        cache_state, mask, deg, cont, base = jax.lax.cond(
+            go, base_read, no_read, (cache_state, visited))
+        ids = jnp.sort(frontier.reshape(-1))
+        base_unique = jnp.sum((ids >= 0) & jnp.concatenate(
+            [jnp.ones((1,), bool), ids[1:] != ids[:-1]]), dtype=jnp.int32)
+        # the packed stage: the pooled continuation rows, F ids an iteration
+        pool = _pool_chains(deg, cont, W, cfg.chain_depth - 1)
+        s = jax.lax.while_loop(lambda s: _global_any(s.it * F < pool.total), packed_body,
+                               _Chain(z, mask, cache_state, z, z, z, z))
+    chain_iters = jnp.stack([go.astype(jnp.int32), s.it])
+    chain_rows = jnp.stack([base[1], s.touched])
+    chain_unique = jnp.stack([base_unique, s.unique])
+    new_mask = s.mask
 
     # new_mask == visited | hop marks: the chain carry was seeded with
     # visited and every backend only ORs bits in, so it is already the
@@ -381,11 +382,12 @@ def expand_hop(
         newly = layout.minus(new_mask, visited)
         nxt, n_new = _first_set(layout.to_dense(newly, n), F)
     visited = new_mask
-    # truncated if the frontier overflowed F, OR the continuation chain was
-    # cut off by the chain_depth cap while rows still had continuations
-    truncated = (n_new > F) | chain_cut
-    return HopResult(visited, nxt, s.cache, truncated, s.reads, s.touched, s.probe_misses,
-                     chain_iters, chain_rows, marks.flushes)
+    # truncated if the frontier overflowed F, OR a chain of this processor
+    # was cut off by the chain_depth cap
+    truncated = (n_new > F) | pool.cut
+    return HopResult(visited, nxt, s.cache, truncated, base[0] + s.reads,
+                     base[1] + s.touched, base[2] + s.probe_misses,
+                     chain_iters, chain_rows, chain_unique)
 
 
 @dataclasses.dataclass
@@ -401,12 +403,14 @@ class QueryStats:
     (per-direction detail of its bi-directional BFS: `truncated` is their
     OR); every other query type leaves them None.
 
-    `chain_iters`/`chain_rows`/`flushes` are the continuation-chain loop's
-    work, populated by `run_neighbor_aggregation`: iterations and live row
-    ids read per hop and per stage of `chain_stage_widths` (`chain_rows`
-    sums to `touched`), and flushes of the buffered narrow-stage marks.
-    Each stage-0 iteration marks its full-width read at once, so the
-    expansions of the visited state are stage-0 iterations plus flushes.
+    `chain_iters`/`chain_rows`/`chain_unique` are the chain loop's work, populated by `run_neighbor_aggregation`, per hop and per
+    stage (0: the base rows, 1: the packed continuation rows of
+    `expand_hop`): iterations, (query, row) pairs read (`chain_rows` sums to
+    `touched`), distinct row ids read after the processor's deduplication
+    (so `chain_rows / chain_unique` is the sharing between its queries and
+    `chain_unique / (iterations x max_frontier)` the packed stage's slot
+    use). Every iteration of either stage marks its read at once, so the
+    expansions of the visited state are the iterations.
     """
 
     touched: jax.Array  # rows needed across hops (hits+misses)
@@ -416,9 +420,9 @@ class QueryStats:
     reads: jax.Array  # unique storage rows fetched
     truncated_fwd: Optional[jax.Array] = None  # (B,) bool, reachability only
     truncated_bwd: Optional[jax.Array] = None  # (B,) bool, reachability only
-    chain_iters: Optional[jax.Array] = None  # (h, stages) int32
-    chain_rows: Optional[jax.Array] = None  # (h, stages) int32
-    flushes: Optional[jax.Array] = None  # () int32
+    chain_iters: Optional[jax.Array] = None  # (h, 2) int32
+    chain_rows: Optional[jax.Array] = None  # (h, 2) int32
+    chain_unique: Optional[jax.Array] = None  # (h, 2) int32
 
 
 def run_neighbor_aggregation(
@@ -447,7 +451,7 @@ def run_neighbor_aggregation(
 
     def hop(i, carry):
         (visited, frontier, cache_state, misses, reads, touched, truncated, touched_map,
-         iters, rows, flushes) = carry
+         iters, rows, unique) = carry
         if touched_map is not None:
             ids = frontier.reshape(-1)
             ok = (ids >= 0) & (ids < n)
@@ -456,23 +460,23 @@ def run_neighbor_aggregation(
         return (res.visited, res.frontier, res.cache, misses + res.probe_misses,
                 reads + res.reads, touched + res.touched, truncated | res.truncated,
                 touched_map, iters.at[i].set(res.chain_iters),
-                rows.at[i].set(res.chain_rows), flushes + res.flushes)
+                rows.at[i].set(res.chain_rows), unique.at[i].set(res.chain_unique))
 
     z = jnp.zeros((), jnp.int32)
-    per_stage = jnp.zeros((h, len(chain_stage_widths(F, cfg.chain_depth))), jnp.int32)
-    # one hop body in the program, looped h times (its chain stages are the
+    per_stage = jnp.zeros((h, 2), jnp.int32)
+    # one hop body in the program, looped h times (its chain loop is the
     # bulk of the program, so unrolling the hops would multiply it by h)
     (visited, _frontier, cache_state, misses, reads, touched, truncated, touched_map,
-     iters, rows, flushes) = jax.lax.fori_loop(
+     iters, rows, unique) = jax.lax.fori_loop(
         0, h, hop, (visited, frontier, cache_state, z, z, z, jnp.zeros((B,), bool),
-                    touched_map, per_stage, per_stage, z))
+                    touched_map, per_stage, per_stage, per_stage))
 
     sizes = layout.count(visited)
     counts = sizes - valid_q.astype(jnp.int32)  # exclude query node
     stats = QueryStats(
         touched=touched, misses=misses, result_sizes=sizes,
         truncated=truncated, reads=reads, chain_iters=iters, chain_rows=rows,
-        flushes=flushes,
+        chain_unique=unique,
     )
     return counts, cache_state, stats, touched_map
 
@@ -500,10 +504,13 @@ def run_random_walk(
             tier_arrays, cache_state, cur, cfg.use_cache, multi_read
         )
         misses, reads, touched = misses + n_miss, reads + n_reads, touched + n_touch
-        # uniform neighbor choice over the first row (paper treats the value
-        # array as the neighbor set; continuation tail neighbors are reached
-        # on later steps through the chain row ids themselves)
-        pick = jax.random.randint(k1, (B,), 0, jnp.maximum(deg, 1))
+        # uniform neighbor choice over the first row's own entries (paper
+        # treats the value array as the neighbor set; continuation tail
+        # neighbors are reached on later steps through the chain row ids
+        # themselves); `deg` is the remaining degree, so the row holds
+        # min(deg, W) of them
+        own = jnp.minimum(deg, cache_state.row_width)
+        pick = jax.random.randint(k1, (B,), 0, jnp.maximum(own, 1))
         nxt = rows[jnp.arange(B), pick]
         nxt = jnp.where(deg > 0, nxt, cur)  # dangling: stay
         restart = jax.random.uniform(k2, (B,)) < restart_prob

@@ -45,6 +45,13 @@ class StorageTier:
     owner(r) == s, densely packed in local slot order. `loc` maps global row
     id -> local slot; `owner` maps global row id -> shard.
     Continuation rows are placed like ordinary rows (their ids >= n).
+
+    A row's `deg` is its node's remaining degree (`PaddedAdjacency`): the
+    value length a key-value multi_read of a base row returns, and of a
+    continuation row what is left of the adjacency. The row's own entries
+    are the first min(deg, W). A node's continuation rows are consecutive
+    ids from its base row's `cont`, so a reader of the base row knows every
+    row id of the chain: cont + j for j < ceil(deg / W) - 1.
     """
 
     n_shards: int

@@ -53,8 +53,9 @@ EXPAND_BACKENDS = ("scatter", "pallas", "pallas-interpret", "auto", "auto-interp
 #
 # Protocol: fn(rows (B, F, W) int32, deg (B, F) int32, mask) -> mask' with
 # every valid neighbor marked, where mask is IN THE LAYOUT'S REPRESENTATION.
-# Valid = row id >= 0, within the row's degree, and < n (continuation-row
-# ids >= n are engine-internal and never enter the bitmap).
+# Valid = row id >= 0, at a column below the row's remaining degree (a row
+# holds min(deg, W) entries), and < n (continuation-row ids >= n are
+# engine-internal and never enter the bitmap).
 # ---------------------------------------------------------------------------
 
 
@@ -227,8 +228,10 @@ def _make_expander(backend: str, n: int, scatter_fn: Callable,
 
     A layout supplies its two execution strategies (`scatter_fn` /
     `pallas_fn`, protocol fn(rows, deg, mask, n[, interpret])) and its
-    density predicate `dense_pred(deg, mask)` for the per-hop `auto` cond;
-    the scatter/pallas/auto name resolution itself exists exactly once."""
+    density predicate `dense_pred(deg, mask)` for the per-hop `auto` cond,
+    which it gives each row's own entry count min(deg, W) (a row's `deg` is
+    its node's remaining degree); the scatter/pallas/auto name resolution
+    itself exists exactly once."""
     interpret = _interpret_mode(backend)
     if backend == "scatter":
         return functools.partial(scatter_fn, n=n)
@@ -237,7 +240,7 @@ def _make_expander(backend: str, n: int, scatter_fn: Callable,
 
     def auto(rows_b, deg_b, mask):
         return jax.lax.cond(
-            dense_pred(deg_b, mask),
+            dense_pred(jnp.minimum(deg_b, rows_b.shape[-1]), mask),
             lambda r, d, m: pallas_fn(r, d, m, n=n, interpret=interpret),
             lambda r, d, m: scatter_fn(r, d, m, n=n),
             rows_b, deg_b, mask,

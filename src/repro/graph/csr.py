@@ -53,12 +53,19 @@ class PaddedAdjacency:
     """Fixed-width adjacency rows; device/storage layout.
 
     rows:   (n_rows, max_degree) int32, -1 padded.
-    degree: (n_rows,) int32 -- number of valid entries in each row (including a
-            possible continuation pointer slot, see ``cont``).
+    degree: (n_rows,) int32 -- REMAINING degree: the adjacency entries from
+            this row to the end of its node's adjacency. A base row holds the
+            node's full degree, continuation row k of a node of degree d
+            holds d - k * max_degree; the row's own entries are the first
+            min(degree, max_degree).
     cont:   (n_rows,) int32 -- continuation row id (>= n base rows) or -1.
             Rows whose true degree exceeds max_degree chain into continuation
             rows appended after the n base rows.
     n:      number of *real* nodes (base rows); n_rows >= n.
+
+    A node's continuation rows are consecutive: segment k >= 1 of node u is
+    row cont[u] + k - 1, so a base row's (degree, cont) name every row of its
+    chain without following it.
     """
 
     n: int
@@ -79,7 +86,7 @@ class PaddedAdjacency:
         out = []
         r = u
         while r != -1:
-            d = self.degree[r]
+            d = min(int(self.degree[r]), self.max_degree)
             out.append(self.rows[r, :d])
             r = int(self.cont[r])
         if not out:
@@ -117,6 +124,10 @@ def to_padded(g: CSRGraph, max_degree: Optional[int] = None) -> PaddedAdjacency:
     """Convert CSR to the padded storage layout with continuation rows.
 
     If max_degree is None, uses the true max degree (no continuations).
+    The contract readers rely on (see `PaddedAdjacency`): a row's degree is
+    the node's remaining degree, and a node's continuation rows are
+    consecutive, so a node of degree d > max_degree owns the rows
+    cont[u] + j for j < ceil(d / max_degree) - 1.
     """
     deg = np.diff(g.indptr).astype(np.int64)
     true_max = int(deg.max()) if g.n else 0
@@ -143,13 +154,13 @@ def to_padded(g: CSRGraph, max_degree: Optional[int] = None) -> PaddedAdjacency:
     row_of = np.where(seg == 0, owner, chain_start[owner] + seg - 1)
     rows[row_of, j % max_degree] = g.indices
 
-    degree[: g.n] = np.minimum(deg, max_degree)
+    degree[: g.n] = deg
     has_chain = n_chain > 0
     cont[: g.n][has_chain] = chain_start[has_chain]
     # chain rows: segment k = 1..n_chain[u] of each chained node u
     c_owner = np.repeat(np.arange(g.n, dtype=np.int64), n_chain)
     c_seg = np.arange(total_rows - g.n, dtype=np.int64) - (chain_start[c_owner] - g.n) + 1
-    degree[g.n :] = np.minimum(deg[c_owner] - c_seg * max_degree, max_degree)
+    degree[g.n :] = deg[c_owner] - c_seg * max_degree
     last = c_seg == n_chain[c_owner]
     cont[g.n :] = np.where(last, -1, np.arange(g.n + 1, total_rows + 1))
     return PaddedAdjacency(n=g.n, rows=rows, degree=degree, cont=cont)

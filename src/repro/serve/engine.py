@@ -466,7 +466,7 @@ class ServingEngine:
             jnp.any(stats.truncated),
             stats.chain_iters,
             stats.chain_rows,
-            stats.flushes,
+            stats.chain_unique,
         )
         return counts, cache, scalars, touched_map
 
@@ -492,7 +492,7 @@ class ServingEngine:
         counts_b, caches, scal, tmap = jax.vmap(
             functools.partial(self._proc_round, store), axis_name=PROC_AXIS,
         )(caches, qbuf, tmap)
-        touched_p, reads_p, probe_p, trunc_p, iters_p, rows_p, flushes_p = scal
+        touched_p, reads_p, probe_p, trunc_p, iters_p, rows_p, unique_p = scal
         with jax.named_scope("admission"):
             counts = scatter_back(counts_b, d, adm.offered_node.shape[0])
         # unplaced (and padded) queries must not masquerade as |N_h(q)|-1 == 0
@@ -522,7 +522,7 @@ class ServingEngine:
             "truncated": trunc_p,
             "chain_iters": iters_p,  # (P, hops, stages)
             "chain_rows": rows_p,  # (P, hops, stages)
-            "flushes": flushes_p,  # (P,)
+            "chain_unique": unique_p,  # (P, hops, stages)
             "stolen": adm.stolen,
             "unplaced": adm.unplaced,
             "backlog_depth": adm.depth,

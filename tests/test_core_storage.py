@@ -50,6 +50,60 @@ def test_continuation_chains_preserve_adjacency(tiny_graph):
         np.testing.assert_array_equal(got, expect)
 
 
+def _chain_rows(adj, u):
+    """Node u's rows by the contract: its base row, then the consecutive
+    continuation rows cont[u] + j, j < ceil(deg / W) - 1."""
+    W, d = adj.max_degree, int(adj.degree[u])
+    n_cont = -(-d // W) - 1 if adj.cont[u] >= 0 else 0
+    return [u] + [int(adj.cont[u]) + j for j in range(n_cont)]
+
+
+@pytest.mark.parametrize("W", [3, 8])
+def test_to_padded_remaining_degree_and_consecutive_chains(tiny_graph, W):
+    """A row's degree is what is left of its node's adjacency from it on
+    (a base row holds the full degree), its own entries are the first
+    min(degree, W), and a node's continuation rows are consecutive ids
+    from its base row's cont, each pointing at the next."""
+    g = tiny_graph
+    adj = to_padded(g, max_degree=W)
+    full = np.diff(g.indptr)
+    np.testing.assert_array_equal(adj.degree[:g.n], full)
+    assert (full > W).any()
+    covered = np.zeros(adj.n_rows, bool)
+    for u in range(g.n):
+        rows = _chain_rows(adj, u)
+        assert (adj.cont[u] >= 0) == (full[u] > W)
+        for k, r in enumerate(rows):
+            assert adj.degree[r] == full[u] - k * W
+            assert adj.cont[r] == (rows[k + 1] if k + 1 < len(rows) else -1)
+            covered[r] = True
+        own = [adj.rows[r, :min(int(adj.degree[r]), W)] for r in rows]
+        np.testing.assert_array_equal(np.concatenate(own), g.neighbors(u))
+        np.testing.assert_array_equal(adj.full_neighbors(u), g.neighbors(u))
+    assert covered.all()  # every row belongs to exactly one node's chain
+
+
+def test_build_storage_keeps_the_chain_contract(tiny_graph):
+    """The placed tier returns each row's remaining degree, and reading a
+    base row names its whole chain: the ids cont + j read in order give the
+    node's adjacency."""
+    W = 4
+    adj = to_padded(tiny_graph, max_degree=W)
+    t = build_storage(adj, n_shards=3)
+    np.testing.assert_array_equal(t.shard_deg[t.owner, t.loc], adj.degree)
+    np.testing.assert_array_equal(t.shard_cont[t.owner, t.loc], adj.cont)
+    hubs = np.argsort(-np.diff(tiny_graph.indptr), kind="stable")[:5].astype(np.int32)
+    rows, deg, cont = (np.asarray(x) for x in multi_read_ref(t, jnp.asarray(hubs)))
+    for i, u in enumerate(hubs):
+        assert deg[i] == tiny_graph.degree()[u] > W
+        ids = cont[i] + np.arange(-(-deg[i] // W) - 1, dtype=np.int32)
+        c_rows, c_deg, _ = (np.asarray(x) for x in multi_read_ref(t, jnp.asarray(ids)))
+        np.testing.assert_array_equal(c_deg, deg[i] - W * np.arange(1, ids.size + 1))
+        got = np.concatenate([rows[i, :W]] + [c_rows[k, :min(c_deg[k], W)]
+                                              for k in range(ids.size)])
+        np.testing.assert_array_equal(got, tiny_graph.neighbors(u))
+
+
 def test_storage_covers_all_rows(tier):
     t, adj = tier
     # every row is placed exactly once, owner/loc consistent
@@ -123,6 +177,39 @@ def test_sharded_multi_read_single_device(tiny_graph):
     np.testing.assert_array_equal(np.asarray(rows), np.asarray(r_rows))
     np.testing.assert_array_equal(np.asarray(deg), np.asarray(r_deg))
     np.testing.assert_array_equal(np.asarray(cont), np.asarray(r_cont))
+
+
+def test_sharded_multi_read_returns_remaining_degree(tiny_graph):
+    """The all_to_all read carries the same remaining degrees: base rows of
+    hubs with their full degree, continuation rows with what is left."""
+    W = 4
+    adj = to_padded(tiny_graph, max_degree=W)
+    t = build_storage(adj, n_shards=1)
+    mesh = _mesh11()
+    hub = int(np.argmax(np.diff(tiny_graph.indptr)))
+    ids = np.array(_chain_rows(adj, hub)[:6] + [5, -1], np.int32)
+
+    def body(ids, rows, deg, cont, owner, loc):
+        return sharded_multi_read(ids, rows[0], deg[0], cont[0], owner, loc,
+                                  axis_name="model", n_shards=1, capacity=16)
+
+    f = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(), P("model"), P("model"), P("model"), P(), P()),
+        out_specs=(P(), P(), P(), P()),
+        check_vma=False,
+    )
+    with mesh:
+        rows, deg, cont, served = jax.jit(f)(
+            jnp.asarray(ids), jnp.asarray(t.shard_rows), jnp.asarray(t.shard_deg),
+            jnp.asarray(t.shard_cont), jnp.asarray(t.owner), jnp.asarray(t.loc),
+        )
+    ok = ids >= 0
+    assert bool(np.asarray(served)[ok].all())
+    np.testing.assert_array_equal(np.asarray(deg)[ok], adj.degree[ids[ok]])
+    np.testing.assert_array_equal(np.asarray(cont)[ok], adj.cont[ids[ok]])
+    np.testing.assert_array_equal(np.asarray(rows)[ok], adj.rows[ids[ok]])
+    assert np.asarray(deg)[0] == tiny_graph.degree()[hub] > W
 
 
 def test_sharded_feature_gather_roundtrip():

@@ -1,13 +1,15 @@
 """The engine's own measurement of its chain loop, and its layer scopes.
 
-Per round and processor the engine returns `chain_iters` and `chain_rows`
-(per hop and per stage of `chain_stage_widths`) and `flushes` (buffered-mark
-flushes). A host replay of the chain loop recounts each from the graph
-alone: a frontier node's chain is its base row plus its continuation rows,
-the processors of a round step through their chains together, a stage runs
-while some query has more live rows than the next stage holds, and a narrow
-read joins a buffer of `max_frontier` rows per query that is flushed when
-the next read would overflow it and at the end of the hop.
+Per round and processor the engine returns, per hop and per stage (0: the
+frontier's base rows, 1: the packed continuation rows), `chain_iters`,
+`chain_rows` ((query, row) pairs read) and `chain_unique` (distinct row ids
+read). A host replay of the chain loop recounts each from the graph alone:
+stage 0 is one full-width read while any processor of the round has a
+frontier; a processor's packed list is its queries' chains, one per node,
+in row-id order, each the node's continuation rows up to the cap; the
+processors step through their lists `max_frontier` ids an iteration
+together, for as many iterations as the longest list needs. Every
+iteration marks its read at once, so the iterations are the expansions.
 
 The layer scopes (`jax.named_scope`) must reach the `op_name` metadata of
 the compiled round, where a profiler trace's device ops find them.
@@ -18,7 +20,6 @@ import re
 import numpy as np
 import pytest
 
-from repro.core.query_engine import chain_stage_widths
 from repro.core.router import Router, RouterConfig
 from repro.core.storage import build_storage
 from repro.core.workloads import Workload
@@ -59,40 +60,27 @@ def _serve(g, queries, chain_depth, layout="packed", backend="scatter"):
 
 
 def _replay(g, per_proc, chain_depth):
-    """Host replay of one round: (iters (hops, stages), rows (P, hops,
-    stages), flushes (P,)) for processors serving `per_proc[p]` queries."""
-    widths = chain_stage_widths(F, chain_depth)
-    deg = g.degree()
-    chain = np.maximum(1, -(-deg // W))  # rows per node: base + continuations
-    iters = np.zeros((HOPS, len(widths)), np.int64)
-    rows = np.zeros((P, HOPS, len(widths)), np.int64)
-    flushes = np.zeros(P, np.int64)
+    """Host replay of one round: (iters (hops, 2), rows (P, hops, 2),
+    unique (P, hops, 2)) for processors serving `per_proc[p]` queries."""
+    chain = np.maximum(1, -(-g.degree() // W))  # rows per node: base + continuations
+    n_cont = np.minimum(chain - 1, chain_depth - 1)  # continuation rows read per hop
+    iters = np.zeros((HOPS, 2), np.int64)
+    rows = np.zeros((P, HOPS, 2), np.int64)
+    unique = np.zeros((P, HOPS, 2), np.int64)
     visited = [[{int(q)} for q in qs] for qs in per_proc]
     frontier = [[np.array([q], np.int64) for q in qs] for qs in per_proc]
 
-    def live(t, p):  # live row ids of each of processor p's queries before iteration t
-        return [int((chain[fq] > t).sum()) for fq in frontier[p]]
-
-    def most_live(t):
-        return max((n for p in range(P) for n in live(t, p)), default=0)
-
     for hop in range(HOPS):
-        it, go, fill = 0, most_live(0) > 0, 0
-        for i, w in enumerate(widths):
-            nxt = widths[i + 1] if i + 1 < len(widths) else None
-            while go and it < chain_depth and (nxt is None or most_live(it) > nxt):
-                for p in range(P):
-                    rows[p, hop, i] += sum(live(it, p))
-                if w != F:  # a narrow read joins the buffer
-                    if fill + w > F:
-                        flushes += 1
-                        fill = 0
-                    fill += w
-                iters[hop, i] += 1
-                it += 1
-                go = most_live(it) > 0
-        if len(widths) > 1 and fill > 0:
-            flushes += 1
+        iters[hop, 0] = any(fq.size for fr in frontier for fq in fr)
+        lists = []  # each processor's packed list: the node owning each position
+        for p in range(P):
+            nodes = np.unique(np.concatenate(frontier[p] or [np.zeros(0, np.int64)]))
+            rows[p, hop] = (sum(fq.size for fq in frontier[p]),
+                            sum(int(n_cont[fq].sum()) for fq in frontier[p]))
+            # chains in row-id order: `to_padded` allocates them in node order
+            lists.append(np.repeat(nodes, n_cont[nodes]))
+            unique[p, hop] = (nodes.size, lists[p].size)
+        iters[hop, 1] = max(-(-lst.size // F) for lst in lists)
         # every node's chain is read up to the cap; the next frontier is the
         # first F newly reached nodes by id
         for p in range(P):
@@ -104,7 +92,7 @@ def _replay(g, per_proc, chain_depth):
                 new -= visited[p][j]
                 visited[p][j] |= new
                 frontier[p][j] = np.array(sorted(new)[:F], np.int64)
-    return iters, rows, flushes
+    return iters, rows, unique
 
 
 def _per_proc(res, queries, r):
@@ -133,32 +121,34 @@ def served(request, graph, whole_chains):
 def test_chain_rows_sum_to_touched(served):
     _, _, _, res = served
     pr = res.per_round
-    assert pr["chain_rows"].shape == pr["chain_iters"].shape == pr["touched"].shape + (HOPS, 3)
+    shape = pr["touched"].shape + (HOPS, 2)
+    assert pr["chain_rows"].shape == pr["chain_iters"].shape == pr["chain_unique"].shape == shape
     np.testing.assert_array_equal(pr["chain_rows"].sum((2, 3)), pr["touched"])
+    # a row read for several queries of a processor is one distinct id
+    assert (pr["chain_unique"] <= pr["chain_rows"]).all()
 
 
 def test_chain_counters_match_a_host_replay(served):
-    """Iterations per hop are the longest chain among the hop's frontier
-    nodes across the round's processors, capped at chain_depth; per stage,
-    iterations, rows read and flushes equal the host replay."""
+    """Per hop, one base read and as many packed iterations as the longest
+    processor list needs at max_frontier ids an iteration; per stage,
+    iterations, rows read and distinct rows read equal the host replay."""
     g, queries, depth, res = served
     pr = res.per_round
-    chain = np.maximum(1, -(-g.degree() // W))
-    stage0 = 0
+    n_cont = np.minimum(np.maximum(1, -(-g.degree() // W)) - 1, depth - 1)
     for r in range(pr["touched"].shape[0]):
         per_proc = _per_proc(res, queries, r)
-        iters, rows, flushes = _replay(g, per_proc, depth)
+        iters, rows, unique = _replay(g, per_proc, depth)
         # the processors share one loop: every processor counts its iterations
         assert (pr["chain_iters"][r] == pr["chain_iters"][r][:1]).all()
         np.testing.assert_array_equal(pr["chain_iters"][r][0], iters)
         np.testing.assert_array_equal(pr["chain_rows"][r], rows)
-        np.testing.assert_array_equal(pr["flushes"][r], flushes)
-        # hop 0's frontier is the queries themselves
-        assert iters[0].sum() == min(int(chain[np.concatenate(per_proc)].max()), depth)
-        stage0 += iters[:, 0].sum()
-    if depth >= chain.max():  # whole chains: the hubs' frontiers fill the full-width stage
-        assert stage0 > 0
-    assert (pr["flushes"] > 0).all()
+        np.testing.assert_array_equal(pr["chain_unique"][r], unique)
+        # hop 0's frontier is the queries themselves: each processor's list
+        # is its distinct queries' continuation rows
+        longest = max(int(n_cont[np.unique(qs)].sum()) for qs in per_proc)
+        np.testing.assert_array_equal(iters[0], [1, -(-longest // F)])
+    # the hubs' chains run the packed stage in every round
+    assert (pr["chain_iters"][..., 1].sum((1, 2)) > 0).all()
 
 
 @pytest.mark.parametrize("layout,backend", [("dense", "scatter"), ("dense", "pallas-interpret"),
@@ -168,7 +158,7 @@ def test_counters_do_not_depend_on_layout_or_backend(whole_chains, layout, backe
     the same under every (layout, backend) cell as under (packed, scatter)."""
     g, queries, depth, ref = whole_chains
     res = _serve(g, queries, depth, layout=layout, backend=backend)
-    for key in ("chain_iters", "chain_rows", "flushes", "touched"):
+    for key in ("chain_iters", "chain_rows", "chain_unique", "touched"):
         np.testing.assert_array_equal(res.per_round[key], ref.per_round[key], err_msg=key)
 
 

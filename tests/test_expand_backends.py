@@ -31,6 +31,7 @@ import pytest
 import jax.numpy as jnp
 
 from repro.core import cache as cache_lib
+from repro.core import visited as visited_lib
 from repro.core.query_engine import (
     EXPAND_BACKENDS, VISITED_LAYOUTS, EngineConfig, get_expand_backend,
     get_visited_layout, make_ref_multi_read, run_neighbor_aggregation,
@@ -105,6 +106,46 @@ def test_packed_kernel_vs_ref(B, F, W, n, label):
     nw = out.shape[1]
     tail = np.asarray(unpack_words(out, nw * 32))[:, n:]
     assert not tail.any(), label
+
+
+@pytest.mark.parametrize("B,F,W,n,label", BATCH_CASES)
+def test_kernels_mask_remaining_degree_as_today(B, F, W, n, label):
+    """Storage rows carry their node's REMAINING degree, up to several row
+    widths: both kernels mark exactly the row's own first min(deg, W)
+    entries, as they mark the same rows with the degree clamped to W."""
+    rows, _, visited = _batch_case(B, F, W, n, seed=B * 977 + n)
+    deg = np.random.default_rng(n).integers(0, 3 * W + 1, (B, F)).astype(np.int32)
+    clamped = jnp.asarray(np.minimum(deg, W))
+    rows, deg, visited = jnp.asarray(rows), jnp.asarray(deg), jnp.asarray(visited)
+    dense = frontier_expand_batched(rows, deg, visited, bf=BF, bn=BN, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(dense),
+        np.asarray(frontier_expand_batched(rows, clamped, visited, bf=BF, bn=BN,
+                                           interpret=True)), err_msg=label)
+    expect = np.stack([np.asarray(ref.frontier_expand_ref(rows[b], clamped[b], visited[b]))
+                       for b in range(B)])
+    np.testing.assert_array_equal(np.asarray(dense), expect, err_msg=label)
+    packed = frontier_expand_packed(rows, deg, pack_words(visited), n, bf=BF, bw=BW,
+                                    interpret=True)
+    np.testing.assert_array_equal(np.asarray(unpack_words(packed, n)), expect,
+                                  err_msg=label)
+
+
+@pytest.mark.parametrize("layout", VISITED_LAYOUTS)
+@pytest.mark.parametrize("backend", ["scatter", "pallas-interpret", "auto-interpret"])
+def test_expanders_mask_remaining_degree_as_today(backend, layout):
+    """Every (backend, layout) expander the engine resolves marks the same
+    bits for a row's remaining degree as for that degree clamped to W."""
+    B, F, W, n = 3, 17, 4, 129
+    rows, _, visited = _batch_case(B, F, W, n, seed=5)
+    deg = np.random.default_rng(6).integers(0, 4 * W, (B, F)).astype(np.int32)
+    lay = get_visited_layout(layout)
+    fn = get_expand_backend(backend, n, layout)
+    mask = lay.from_dense(jnp.asarray(visited))
+    got = fn(jnp.asarray(rows), jnp.asarray(deg), mask)
+    want = get_expand_backend("scatter", n, "dense")(
+        jnp.asarray(rows), jnp.asarray(np.minimum(deg, W)), jnp.asarray(visited))
+    np.testing.assert_array_equal(np.asarray(lay.to_dense(got, n)), np.asarray(want))
 
 
 def test_ops_single_query_packed_wrapper():
@@ -341,6 +382,40 @@ def test_dense_frontier_packed_heuristic():
     assert not bool(dense_frontier(deg, n=n))
 
 
+def test_density_predicates_count_a_rows_own_entries():
+    """With remaining degrees beyond the row width, the `auto` backend's
+    choice under both density predicates is the one it makes on the degrees
+    clamped to W: a row offers at most W candidates, however long its
+    node's chain. The two strategies are stubbed so the output names the
+    branch taken."""
+    B, F, W = 4, 8, 8
+    rows = jnp.zeros((B, F, W), jnp.int32)
+    remaining = jnp.full((B, F), 5 * W, jnp.int32)  # hubs: 4 more rows each
+    clamped = jnp.full((B, F), W, jnp.int32)
+    occ = pack_words(jnp.asarray(np.random.default_rng(0).random((B, 1000)) < 0.6))
+    words = {"empty": jnp.zeros_like(occ), "occupied": occ}
+
+    def chosen(pred, deg, mask):
+        auto = visited_lib._make_expander(
+            "auto-interpret", 1000, lambda r, d, m, n: jnp.int32(0),
+            lambda r, d, m, n, interpret: jnp.int32(1), pred)
+        return ("scatter", "pallas")[int(auto(rows, deg, mask))]
+
+    preds = {"dense": lambda d, m: dense_frontier(d, n=1000),
+             "packed": lambda d, m: dense_frontier_packed(d, m, n=1000)}
+    seen = set()
+    for name, pred in preds.items():
+        for wname, mask in words.items():
+            got = chosen(pred, remaining, mask)
+            assert got == chosen(pred, clamped, mask), (name, wname)
+            seen.add(got)
+    assert seen == {"scatter", "pallas"}  # the cases reach both branches
+    # the clamp matters: summed unclamped, the remaining degrees say "dense"
+    # where the rows' own 256 candidates do not
+    assert bool(dense_frontier(remaining, n=1000))
+    assert not bool(dense_frontier(clamped, n=1000))
+
+
 # ---------------------------------------------------------------------------
 # retrace churn: padding buckets frontier sizes; block sizes never clamp
 # ---------------------------------------------------------------------------
@@ -407,3 +482,110 @@ def test_frontier_expand_matches_ref_after_padding_change():
     expect = ref.frontier_expand_ref(jnp.asarray(rows), jnp.asarray(deg),
                                      jnp.asarray(visited))
     np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
+
+
+# ---------------------------------------------------------------------------
+# hub continuation chains through the served engine
+# ---------------------------------------------------------------------------
+
+HUB_W, HUB_P, HUB_H, HUB_F = 4, 2, 2, 320
+
+
+@pytest.fixture(scope="module")
+def hub_round(tiny_graph):
+    """One round of the hub's neighbours: on 2 processors some processor
+    serves several of them, so the hub's chain sits in several of its
+    queries' hop-1 frontiers."""
+    from repro.core.workloads import Workload
+
+    g = tiny_graph
+    hub = int(np.argmax(g.degree()))
+    q = g.neighbors(hub)[:7].astype(np.int32)
+    wl = Workload(name="hub", query_nodes=q, query_types=np.zeros(q.size, np.int8),
+                  targets=np.full(q.size, -1, np.int32),
+                  hotspot_id=np.full(q.size, -1, np.int32))
+    adj = to_padded(g, max_degree=HUB_W)
+    return g, adj, build_storage(adj, n_shards=3), wl
+
+
+def _host_hop_ball(g, adj, q, depth):
+    """Host BFS that reads each frontier node's first `depth` rows (its
+    whole adjacency when the chain fits): (answer, (query, row) pairs read,
+    row ids read, some chain cut)."""
+    W = adj.max_degree
+    seen, frontier = {q}, [q]
+    touched, row_ids, cut = 0, set(), False
+    for _ in range(HUB_H):
+        new = set()
+        for v in frontier:
+            n_rows = max(1, -(-int(g.degree()[v]) // W))
+            k = min(n_rows, depth)
+            cut |= n_rows > depth
+            touched += k
+            row_ids.update([v] + [int(adj.cont[v]) + j for j in range(k - 1)])
+            new.update(g.neighbors(v)[:k * W].tolist())
+        frontier = sorted(new - seen)[:HUB_F]
+        seen |= set(frontier)
+    return len(seen) - 1, touched, row_ids, cut
+
+
+@pytest.mark.parametrize("layout", VISITED_LAYOUTS)
+@pytest.mark.parametrize("backend", ["scatter", "pallas-interpret", "auto-interpret"])
+def test_hub_chains_match_host_bfs_and_oracle(hub_round, backend, layout):
+    """A hub's chain longer than chain_depth, and the same hub shared by
+    several queries of one processor: answers, rows touched, storage reads
+    (a cold cache far larger than the working set: every distinct row once
+    per processor) and truncation equal a host BFS reading the same rows,
+    for whole chains and capped ones; with whole chains the answers are the
+    BFS balls and the touch sets, loads and reads those of the simulator
+    oracle, plus each hub's continuation rows."""
+    from repro.core.router import Router, RouterConfig
+    from repro.core.serving import ServingSimulator, SimRouter, SimRouterConfig, hhop_ball
+    from repro.serve.engine import EngineRunConfig, ServingEngine
+
+    g, adj, tier, wl = hub_round
+    hub = int(np.argmax(g.degree()))
+    whole = int(-(-g.degree().max() // HUB_W))
+    assert whole > 3  # the hub's chain is longer than the capped depth
+    for depth in (whole, 3):
+        cfg = EngineRunConfig(
+            n_processors=HUB_P, round_size=8, capacity=8, hops=HUB_H, max_frontier=HUB_F,
+            cache_sets=1024, cache_ways=8, chain_depth=depth, track_touched=True,
+            expand_backend=backend, visited_layout=layout)
+        res, _ = ServingEngine(tier, Router(HUB_P, RouterConfig(scheme="hash"), seed=0),
+                               cfg).run(wl)
+        assert res.completed.all()
+        shared = np.bincount(res.assignment, minlength=HUB_P).max()
+        assert shared >= 2  # the hub is in each of these queries' hop-1 frontier
+        per_round = res.per_round
+        for p in range(HUB_P):
+            mine = wl.query_nodes[res.assignment == p]
+            host = [_host_hop_ball(g, adj, int(q), depth) for q in mine]
+            for q, (answer, _, _, _) in zip(mine, host):
+                assert res.counts[list(wl.query_nodes).index(q)] == answer, (q, depth)
+            assert res.per_proc_touched[p] == sum(h[1] for h in host), depth
+            rows = set().union(*(h[2] for h in host)) if host else set()
+            assert res.per_proc_reads[p] == len(rows), depth
+            assert bool(per_round["truncated"][0, p]) == any(h[3] for h in host), depth
+            # rows sum to touched; distinct ids never exceed the pairs read
+            assert per_round["chain_rows"][0, p].sum() == res.per_proc_touched[p]
+            assert (per_round["chain_unique"][0, p] <= per_round["chain_rows"][0, p]).all()
+        assert res.truncated == (depth < whole)
+        # the shared hub's chain is read once for its queries in hop 1
+        hop1 = (per_round["chain_unique"][0, :, 1, 1], per_round["chain_rows"][0, :, 1, 1])
+        assert (hop1[0] < hop1[1]).any()
+        if depth < whole:
+            continue
+        for i, q in enumerate(wl.query_nodes):
+            assert res.counts[i] == hhop_ball(g, int(q), HUB_H)[1] - 1
+        sim = ServingSimulator(g, HUB_P, SimRouter(HUB_P, SimRouterConfig(scheme="hash")),
+                               cache_entries=1 << 14, h=HUB_H, steal=False)
+        sres = sim.run(wl, assignments=res.assignment)
+        np.testing.assert_array_equal(sres.per_proc_queries, res.per_proc_queries)
+        etouch = res.touch_sets()
+        n_rows = np.maximum(1, -(-g.degree() // HUB_W))
+        for p in range(HUB_P):
+            assert etouch[p] == sres.touched_sets[p]
+            assert hub in etouch[p] or not (res.assignment == p).any()
+            extra = int(sum(n_rows[v] - 1 for v in etouch[p]))
+            assert res.per_proc_reads[p] == sres.per_proc_misses[p] + extra

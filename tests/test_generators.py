@@ -40,7 +40,8 @@ def _powerlaw_reference(n: int, m: int, seed: int):
 
 
 def _padded_reference(g, max_degree: int):
-    """Row-by-row continuation chaining (the layout `to_padded` defines)."""
+    """Row-by-row continuation chaining (the layout `to_padded` defines):
+    each row's degree is what is left of its node's adjacency from it on."""
     deg = np.diff(g.indptr)
     n_chain = np.where(deg <= max_degree, 0, -(-(deg - max_degree) // max_degree))
     total = g.n + int(n_chain.sum())
@@ -54,7 +55,7 @@ def _padded_reference(g, max_degree: int):
         while True:
             take = min(max_degree, len(nb) - off)
             rows[r, :take] = nb[off : off + take]
-            degree[r] = take
+            degree[r] = len(nb) - off
             off += take
             if off >= len(nb):
                 break
