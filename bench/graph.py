@@ -8,12 +8,15 @@ seed gives the same graph as the program's generator.
 The served graph is that structure under a node relabelling drawn from the
 configuration's `label_seed`, so that node ids say nothing of a node's age
 or degree, and its queries are the traffic's queries on the structure under
-the same relabelling. Every run serves that one graph and those queries; the
-run's seed orders the queries within each round (`run.seed_order`). A
-relabelling drawn from the run's seed changed the work (storage placement,
-cache sets, and which frontier nodes a truncation by node id keeps): on a
-TPU v5e it moved a round's time by up to 1% from seed to seed, against 0.1%
-between two runs of one seed.
+the same relabelling. Every run serves that one graph and those queries, in
+the same rounds and the same order within each round (`run.seed_order`, from
+the traffic's query seed); the run's seed picks only the rounds whose answers
+are checked. A relabelling drawn from the run's seed changed the work
+(storage placement, cache sets, and which frontier nodes a truncation by node
+id keeps): on a TPU v5e it moved a round's time by up to 1% from seed to
+seed, against 0.1% between two runs of one seed. So did an order drawn from
+the run's seed, once each processor pooled the hub chains of the queries it
+holds: the order decides which queries share a processor.
 """
 
 from __future__ import annotations

@@ -17,12 +17,19 @@ Run from the root of a checkout. The cell is an entry of `workloads` in
 
 One run, in order: build the configuration's graph and storage from it with
 the program's loaders, place them on the device, put the queries of each of
-the traffic's rounds in an order drawn from --seed, warm every shape the
+the traffic's rounds in the order its query seed gives, warm every shape the
 window uses, serve a closed loop for --seconds (each client sends its next
 query when its last one completes), check the answers against the plain host
 reference in `reference.py`, and print one JSON line last. With --trace 1
 the window is traced and the per-layer metrics are reported instead of the
 end-to-end ones.
+
+Every run of a cell sends the same queries in the same rounds and in the same
+order, so it does the same work: which processor and cache slot serves a
+query, and so which hub chains a processor pools, follows from the order.
+--seed picks only the rounds whose answers are checked (`check_rounds`). A
+change is thus measured on the one order, as on the one query sequence, that
+its author could also see.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits non-zero
 and prints no result. `--rehearse` runs a cell on the CPU at the size its
@@ -163,8 +170,9 @@ def storage(cfg: dict, g):
 
 def seed_order(pool: np.ndarray, block: int, rng: np.random.Generator) -> np.ndarray:
     """The pool with each run of `block` consecutive queries, what one round
-    of the closed loop sends together, in an order drawn from `rng`: every
-    seed sends the same queries in the same rounds, so it does the same work."""
+    of the closed loop sends together, in an order drawn from `rng`. `main`
+    draws it from the traffic's query seed, never from the run's: the order
+    decides which queries share a processor and so the work of a round."""
     out = pool.copy()
     full = pool.size // block * block
     out[:full] = rng.permuted(pool[:full].reshape(-1, block), axis=1).reshape(-1)
@@ -340,8 +348,9 @@ def main(argv=None, root: Optional[str] = None, chain_cap: Optional[int] = None)
         served = path.Served(cfg, tier, chain_depth, devices)
     del tier
     clients = int(traffic["clients"])
-    # the traffic's rounds, each in this run's order
-    pool = seed_order(pool, min(clients, served.round_size), np.random.default_rng([args.seed, 0]))
+    # the traffic's rounds, each in the traffic's own order: the same in every run
+    pool = seed_order(pool, min(clients, served.round_size),
+                      np.random.default_rng([int(traffic["query_seed"]), 2]))
     # warm-up: the window's shapes, on rounds of padding slots only. The
     # programs are the window's (the first round starts from fresh state,
     # the second from carried state), the work nearly none, and every seed's

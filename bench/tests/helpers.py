@@ -14,8 +14,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 # cells whose files are all in bench/ but which BENCHMARK.json does not hold
 # yet; their rehearsals keep those files working until a change adds them
 CANDIDATES = [
-    {"name": "web1hop.uniform", "config": "grouting-web-1hop", "traffic": "uniform",
-     "chips": 1, "why": "1-hop queries uniform over the nodes"},
     {"name": "web3hop-x4.hotspot", "config": "grouting-web-3hop-x4", "traffic": "hotspot",
      "chips": 4, "why": "3-hop hotspot queries over storage sharded on 4 chips"},
 ]

@@ -43,8 +43,9 @@ def test_relabel_is_an_isomorphism_and_keeps_the_shape():
 
 @pytest.mark.parametrize("size,block", [(64 * 5, 64), (1000, 64), (40, 8)])
 def test_seed_order_keeps_each_rounds_queries(size, block):
-    """Every seed sends the same queries in the same rounds, each round in
-    its own order; the same seed gives the same order."""
+    """Each round keeps its queries under any order; the same generator seed
+    gives the same order, another seed another. The harness draws it from the
+    traffic's query seed alone (test_bench_order.py)."""
     pool = np.random.default_rng(1).integers(0, 10**6, size)
     a = run.seed_order(pool, block, np.random.default_rng([2**31 + 5, 0]))
     b = run.seed_order(pool, block, np.random.default_rng([2**31 + 6, 0]))
